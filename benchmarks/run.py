@@ -26,6 +26,7 @@ from benchmarks import (
     table3_intralayer,
     tier_bench,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "fig9": fig9_activation_sweep,
@@ -51,6 +52,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(MODULES))
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(MODULES)
     print("name,us_per_call,derived")
     failed = []

@@ -1,25 +1,14 @@
-"""Helpers shared by every Pallas kernel in this package.
-
-Single home for the rounding/padding arithmetic and the TPU compiler-params
-shim that ``bitplane_matmul``, ``pack_quant``, ``fused_matmul``,
-``flash_attention`` and ``wkv6`` previously each re-declared.
-"""
+"""Helpers shared by every Pallas kernel in this package: the rounding
+arithmetic and the TPU compiler params each ``pallas_call`` passes."""
 from __future__ import annotations
 
-try:  # TPU compiler params are optional in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    def compiler_params(dims):
-        try:
-            return pltpu.CompilerParams(dimension_semantics=dims)
-        except AttributeError:  # older naming
-            return pltpu.TPUCompilerParams(dimension_semantics=dims)
 
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-    def compiler_params(dims):
-        return None
+def compiler_params(dims):
+    """Mosaic compiler params naming each grid dimension's semantics
+    ("parallel" | "arbitrary"); interpret mode ignores them."""
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def round_up(x: int, mult: int) -> int:

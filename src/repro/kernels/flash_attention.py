@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import compiler_params as _compiler_params
 from repro.kernels.common import round_up as _round_up
@@ -114,16 +115,11 @@ def flash_attention(
         _flash_kernel, bq=bq_, bk=bk_, causal=causal, window=window,
         q_offset=q_offset, kv_len=Tk, scale=scale, n_kb=n_kb,
     )
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        scratch = [
-            pltpu.VMEM((bq_,), jnp.float32),
-            pltpu.VMEM((bq_,), jnp.float32),
-            pltpu.VMEM((bq_, D), jnp.float32),
-        ]
-    except Exception:  # pragma: no cover
-        scratch = []
+    scratch = [
+        pltpu.VMEM((bq_,), jnp.float32),
+        pltpu.VMEM((bq_,), jnp.float32),
+        pltpu.VMEM((bq_, D), jnp.float32),
+    ]
     out = pl.pallas_call(
         kernel,
         grid=(BH, Tqp // bq_, n_kb),

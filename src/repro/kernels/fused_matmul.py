@@ -69,7 +69,7 @@ def _fused_kernel(
     inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
     s_ref[...] = scale
 
-    xs = jax.lax.dynamic_slice_in_dim(x, kk * bk, bk, axis=1)
+    xs = x_ref[:, pl.ds(pl.multiple_of(kk * bk, bk), bk)].astype(jnp.float32)
     q = jnp.clip(jnp.round(xs * inv), qlo, qhi).astype(jnp.int32)
 
     # --- plane decompose + contract (same algebra as bitplane_matmul) ---

@@ -15,7 +15,6 @@ It owns:
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -30,21 +29,6 @@ from repro.kernels import paged_prefill as _paged_pf
 from repro.kernels import ref as _ref
 from repro.kernels import wkv6 as _wkv6
 from repro.kernels.registry import KernelBackend, get_registry, use_backend  # noqa: F401
-
-
-def set_interpret(value: bool) -> None:
-    """Deprecated shim over the kernel registry.
-
-    Use ``get_registry().set_active("interpret"|"mosaic")`` or the scoped
-    ``use_backend(...)`` context manager instead.
-    """
-    warnings.warn(
-        "set_interpret is deprecated; select a backend through "
-        "repro.kernels.registry (get_registry().set_active / use_backend)",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    get_registry().set_active("interpret" if value else "mosaic")
 
 
 def pick_matmul_blocks(

@@ -74,7 +74,7 @@ def _quantize_tile(x):
 
 
 def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                  *rest, bs: int, n_blk: int, lc: int, scale: float,
+                  *rest, bs: int, n_blk: int, lc: int, g: int, scale: float,
                   softcap: float, quantized: bool):
     if quantized:
         (ks_ref, vs_ref, o_ref, pk_out, pv_out, ks_out, vs_out,
@@ -98,56 +98,70 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _step():
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
-        cidx = kpos - start
-        in_chunk = jnp.logical_and(cidx >= 0, cidx < length)
-        gather = jnp.clip(cidx, 0, lc - 1)
-        kn = jnp.take(kn_ref[...], gather, axis=0)    # (bs, bh, H)
-        vn = jnp.take(vn_ref[...], gather, axis=0)
-        sel = in_chunk[:, None, None]
+        # Chunk rows [j*bs - start, +bs) as one contiguous window of the
+        # chunk K/V, which the wrapper pads by bs rows on each side so the
+        # window never leaves the buffer. Rows outside the chunk are
+        # replaced by pool values below, so the clamp changes nothing.
+        off = jnp.clip(j * bs - start, -bs, lc) + bs
+        kn = kn_ref[pl.ds(off, bs)]                   # (bs, bh, H)
+        vn = vn_ref[pl.ds(off, bs)]
+
+        def in_chunk(shape):
+            cidx = j * bs - start + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0)
+            return jnp.logical_and(cidx >= 0, cidx < length)
+
+        sel = in_chunk(kn.shape)
         if quantized:
             kq, ksc = _quantize_tile(kn)
             vq, vsc = _quantize_tile(vn)
+            sel1 = in_chunk(ksc.shape)
             mk = jnp.where(sel, kq, k_ref[0])
             mv = jnp.where(sel, vq, v_ref[0])
-            msk = jnp.where(sel, ksc, ks_ref[0])      # (bs, bh, 1) fp32
-            msv = jnp.where(sel, vsc, vs_ref[0])
+            msk = jnp.where(sel1, ksc, ks_ref[0])     # (bs, bh, 1) fp32
+            msv = jnp.where(sel1, vsc, vs_ref[0])
         else:
             mk = jnp.where(sel, kn.astype(k_ref.dtype), k_ref[0])
             mv = jnp.where(sel, vn.astype(v_ref.dtype), v_ref[0])
 
-        q = q_ref[...].astype(jnp.float32)            # (bh, Lc, G, H)
-        # (bh, Lc, G, H) x (bs, bh, H) -> (bh, Lc, G, bs), batched over bh.
+        q = q_ref[...].astype(jnp.float32)            # (bh, Lc*G, H)
+        # (bh, Lc*G, H) x (bh, H, bs) -> (bh, Lc*G, bs), batched over bh.
         s = jax.lax.dot_general(
-            q, mk.astype(jnp.float32), (((3,), (2,)), ((0,), (1,))),
+            q, mk.astype(jnp.float32).transpose(1, 2, 0),
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         if quantized:
             # Per-key-slot dequant of int8 codes (scores on codes, then
             # rescale — decode_attention's order).
-            s = s * msk[..., 0].transpose(1, 0)[:, None, None, :]
+            s = s * msk[..., 0].transpose(1, 0)[:, None, :]
         s = s * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (lc,), 0)
-        qpos = jnp.where(qi < length, start + qi, -1)  # padded queries: none
-        mask = kpos[None, None, None, :] <= qpos[None, :, None, None]
+        # Row r of the query tile is chunk query r // G; padded queries
+        # (index >= length) see nothing.
+        qi = jax.lax.broadcasted_iota(jnp.int32, (1, lc * g, bs), 1)
+        if g > 1:
+            qi = qi // g
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, lc * g, bs), 2)
+        mask = jnp.logical_and(kpos <= start + qi, qi < length)
         s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=3))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
         safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - safe_m[..., None])
+        p = jnp.exp(s - safe_m[:, :, None])
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=3)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2)
         if quantized:
             # Per-value-slot dequant folded into the probabilities.
-            p = p * msv[..., 0].transpose(1, 0)[:, None, None, :]
+            p = p * msv[..., 0].transpose(1, 0)[:, None, :]
         pv = jax.lax.dot_general(
-            p, mv.astype(jnp.float32), (((3,), (0,)), ((0,), (1,))),
+            p, mv.astype(jnp.float32).transpose(1, 0, 2),
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+        acc_ref[...] = acc_ref[...] * alpha[:, :, None] + pv
         m_ref[...] = m_new
 
         # Epilogue: destination steps write the merged tile back to the
@@ -164,7 +178,7 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
     @pl.when(j == n_blk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[:, :, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -195,15 +209,17 @@ def paged_prefill_attention(
     if bh <= 0 or NKV % bh:
         bh = NKV
     quantized = k_scale is not None
-    qr = q.reshape(Lc, NKV, G, H).transpose(1, 0, 2, 3)  # (NKV, Lc, G, H)
-    kn = k_new.reshape(Lc, NKV, H)
-    vn = v_new.reshape(Lc, NKV, H)
+    # (NKV, Lc*G, H): a KV head's G query heads of every chunk query.
+    qr = q.reshape(Lc, NKV, G, H).transpose(1, 0, 2, 3).reshape(NKV, Lc * G, H)
+    pad = ((bs, bs), (0, 0), (0, 0))
+    kn = jnp.pad(k_new.reshape(Lc, NKV, H), pad)     # (Lc + 2*bs, NKV, H)
+    vn = jnp.pad(v_new.reshape(Lc, NKV, H), pad)
     blocks = blocks.astype(jnp.int32)
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(length, jnp.int32)])
 
     def q_map(h, j, tbl, mt):
-        return (h, 0, 0, 0)
+        return (h, 0, 0)
 
     def new_map(h, j, tbl, mt):
         return (0, h, 0)
@@ -223,20 +239,20 @@ def paged_prefill_attention(
         return (jnp.where(dst, jnp.maximum(tbl[j], 0), 0), 0, h, 0)
 
     in_specs = [
-        pl.BlockSpec((bh, Lc, G, H), q_map),
-        pl.BlockSpec((Lc, bh, H), new_map),
-        pl.BlockSpec((Lc, bh, H), new_map),
+        pl.BlockSpec((bh, Lc * G, H), q_map),
+        pl.BlockSpec((Lc + 2 * bs, bh, H), new_map),
+        pl.BlockSpec((Lc + 2 * bs, bh, H), new_map),
         pl.BlockSpec((1, bs, bh, H), blk_map),
         pl.BlockSpec((1, bs, bh, H), blk_map),
     ]
     operands = [qr, kn, vn, pool_k, pool_v]
     out_shapes = [
-        jax.ShapeDtypeStruct((NKV, Lc, G, H), q.dtype),
+        jax.ShapeDtypeStruct((NKV, Lc * G, H), q.dtype),
         jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
         jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype),
     ]
     out_specs = [
-        pl.BlockSpec((bh, Lc, G, H), q_map),
+        pl.BlockSpec((bh, Lc * G, H), q_map),
         pl.BlockSpec((1, bs, bh, H), dst_map),
         pl.BlockSpec((1, bs, bh, H), dst_map),
     ]
@@ -259,7 +275,7 @@ def paged_prefill_attention(
         aliases.update({7: 3, 8: 4})
 
     kernel = functools.partial(
-        _chunk_kernel, bs=bs, n_blk=mb, lc=Lc, scale=H**-0.5,
+        _chunk_kernel, bs=bs, n_blk=mb, lc=Lc, g=G, scale=H**-0.5,
         softcap=softcap, quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -268,9 +284,9 @@ def paged_prefill_attention(
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((bh, Lc, G), jnp.float32),
-            pltpu.VMEM((bh, Lc, G), jnp.float32),
-            pltpu.VMEM((bh, Lc, G, H), jnp.float32),
+            pltpu.VMEM((bh, Lc * G), jnp.float32),
+            pltpu.VMEM((bh, Lc * G), jnp.float32),
+            pltpu.VMEM((bh, Lc * G, H), jnp.float32),
         ],
     )
     outs = pl.pallas_call(
@@ -281,7 +297,8 @@ def paged_prefill_attention(
         input_output_aliases=aliases,
         interpret=interpret,
     )(blocks, meta, *operands)
-    attn = outs[0].transpose(1, 0, 2, 3).reshape(1, Lc, NQ, H)
+    attn = outs[0].reshape(NKV, Lc, G, H).transpose(1, 0, 2, 3)
+    attn = attn.reshape(1, Lc, NQ, H)
     if quantized:
         return attn, outs[1], outs[2], outs[3], outs[4]
     return attn, outs[1], outs[2], None, None

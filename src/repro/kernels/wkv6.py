@@ -35,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import compiler_params as _compiler_params
 
@@ -118,17 +119,9 @@ def wkv6(
         ],
         out_specs=pl.BlockSpec((1, chunk, V), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((H, Tp, V), jnp.float32),
-        scratch_shapes=[_vmem_scratch(K, V)],
+        scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         compiler_params=_compiler_params(("arbitrary", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, wt, u[:, None, :])
     return jnp.swapaxes(out, 0, 1)[:T]
 
-
-def _vmem_scratch(K: int, V: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM((K, V), jnp.float32)
-    except Exception:  # pragma: no cover — interpret fallback
-        return pl.MemorySpace.ANY((K, V), jnp.float32)

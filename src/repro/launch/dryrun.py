@@ -2,7 +2,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # The two lines above MUST precede every other import: jax locks the device
 # count at first initialization, and the multi-pod dry-run needs 512
-# placeholder host devices to build the production mesh.
+# placeholder host devices to build the production mesh. A CPU-only tool:
+# run it with JAX_PLATFORMS=cpu, never on the chip path.
 
 """Multi-pod dry-run: AOT lower + compile every (arch × shape × mesh) cell.
 
@@ -37,7 +38,7 @@ from repro.models import build_model
 from repro.optim import adamw
 from repro.parallel import sharding as shlib
 from repro.roofline import analysis as roof
-from repro.train.loop import TrainState, make_train_step
+from repro.train.loop import TrainState, make_train_step, train_state_shardings
 
 
 def _parse_quant(s: str):
@@ -120,7 +121,6 @@ def lower_cell(
     batch_shardings = jax.tree_util.tree_map(
         lambda s: shlib.batch_sharding(mesh, s, baxes), specs
     )
-    repl = shlib.replicated(mesh)
 
     if shape.kind == "train":
         tc = TrainConfig(microbatches=microbatches)
@@ -128,11 +128,8 @@ def lower_cell(
         state_shape = jax.eval_shape(
             lambda p: TrainState(p, adamw.init_state(p), None), params_shape
         )
-        state_shardings = TrainState(
-            params=param_shardings,
-            opt=adamw.AdamState(step=repl, mu=param_shardings, nu=param_shardings),
-            err=None,
-        )
+        state_shardings = train_state_shardings(params_shape, tc, mesh,
+                                                cfg.fsdp)
         jitted = jax.jit(
             step,
             in_shardings=(state_shardings, batch_shardings),

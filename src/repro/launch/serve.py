@@ -98,12 +98,17 @@ host instead of re-prefilling cold. Swap/host-hit counters are
 reported after a continuous run.
 """
 import argparse
+import dataclasses
+import time
 
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 
-def main():
+
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's flags (see the module docstring)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -206,8 +211,13 @@ def main():
                          "loaded into the host tier at startup if it "
                          "exists, saved back on exit (needs "
                          "--host-pool-bytes)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args):
+    """Check the flag combination, select the kernel backend, build
+    the model (restored from --ckpt, else random weights from
+    PRNGKey(0)) and the ServingEngine. Returns (cfg, engine)."""
     if args.index and not args.host_pool_bytes:
         raise SystemExit("--index persists blocks into the host tier; "
                          "add --host-pool-bytes")
@@ -245,11 +255,9 @@ def main():
             n = get_registry().load_plans(args.plans)
             print(f"loaded {n} block plans from {args.plans}")
 
-    import dataclasses
-
     from repro.configs import get_config, get_reduced_config
     from repro.models import build_model
-    from repro.serving import Request, ServingEngine
+    from repro.serving import ServingEngine
 
     cfg = (get_reduced_config if args.reduced else get_config)(args.arch)
     if cfg.family == "encoder":
@@ -313,44 +321,56 @@ def main():
         if os.path.exists(args.index):
             n = engine.load_index(args.index)
             print(f"loaded {n} prefix digests from {args.index}")
+    return cfg, engine
 
-    def make_requests():
-        # Self-contained stream: every call reproduces the exact same
-        # requests (shared system prompt, tails, arrivals), so the timed
-        # pass serves precisely the stream the warmup pass compiled for.
-        rng = np.random.default_rng(0)
-        shared = rng.integers(0, cfg.vocab, args.shared_prefix)
-        tier_list = (args.tiers.split(",") if args.tiers else [None])
-        reqs = [Request(rid=i,
-                        prompt=np.concatenate([
-                            shared, rng.integers(0, cfg.vocab, 8 + (i % 5))
-                        ]).astype(np.int64),
-                        max_new_tokens=args.max_new,
-                        temperature=0.0 if i % 2 == 0 else 0.7,
-                        tier=tier_list[i % len(tier_list)],
-                        deadline_s=(args.deadline_ms / 1e3
-                                    if args.deadline_ms else None))
-                for i in range(args.requests)]
-        if args.continuous and args.rate > 0:
-            t = 0.0
-            for r in reqs:
-                r.arrival_time = t
-                t += float(rng.exponential(1.0 / args.rate))
-        return reqs
 
+def make_requests(args, cfg):
+    """The synthetic request stream. Self-contained: every call
+    reproduces the exact same requests (shared system prompt, tails,
+    arrivals), so the timed pass serves precisely the stream the warmup
+    pass compiled for."""
+    from repro.serving import Request
+
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, cfg.vocab, args.shared_prefix)
+    tier_list = (args.tiers.split(",") if args.tiers else [None])
+    reqs = [Request(rid=i,
+                    prompt=np.concatenate([
+                        shared, rng.integers(0, cfg.vocab, 8 + (i % 5))
+                    ]).astype(np.int64),
+                    max_new_tokens=args.max_new,
+                    temperature=0.0 if i % 2 == 0 else 0.7,
+                    tier=tier_list[i % len(tier_list)],
+                    deadline_s=(args.deadline_ms / 1e3
+                                if args.deadline_ms else None))
+            for i in range(args.requests)]
+    if args.continuous and args.rate > 0:
+        t = 0.0
+        for r in reqs:
+            r.arrival_time = t
+            t += float(rng.exponential(1.0 / args.rate))
+    return reqs
+
+
+def serve_passes(args, cfg, engine):
+    """Serve the request stream twice: a warm-up pass that compiles,
+    then the timed pass. Returns (done, t_warm, dt) in seconds."""
     serve = engine.generate if args.continuous else engine.generate_static
-    import time
-
     # Warmup: one full pass compiles every prefill bucket + the decode
     # step, so the timed pass measures steady-state serving.
     t0 = time.perf_counter()
-    serve(make_requests())
+    serve(make_requests(args, cfg))
     t_warm = time.perf_counter() - t0
 
-    reqs = make_requests()          # identical request stream, warm jit
+    reqs = make_requests(args, cfg)  # identical request stream, warm jit
     t1 = time.perf_counter()
     done = serve(reqs)
     dt = time.perf_counter() - t1
+    return done, t_warm, dt
+
+
+def report(args, engine, done, t_warm, dt):
+    """Print throughput, latency and the pool counters of a run."""
     total = sum(len(r.out_tokens or ()) for r in done)
     mode = "continuous" if args.continuous else "static"
     print(f"{len(done)} requests, {total} tokens, {dt:.1f}s [{mode}]")
@@ -445,7 +465,17 @@ def main():
           f"kv_int8={args.kv_int8}")
     for r in sorted(done, key=lambda r: r.rid)[:4]:
         print(f"  req {r.rid}: {(r.out_tokens or [])[:10]}")
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, engine = build_engine(args)
+    done, t_warm, dt = serve_passes(args, cfg, engine)
+    report(args, engine, done, t_warm, dt)
     if args.plans:
+        from repro.kernels import get_registry
+
         n = get_registry().save_plans(args.plans)
         print(f"saved {n} block plans to {args.plans}")
     if args.index:

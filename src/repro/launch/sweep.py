@@ -5,6 +5,10 @@ one JSON record to the output file; the sweep is resumable — cells already
 recorded are skipped. Skipped-by-applicability cells are recorded too, so
 the output accounts for all 40 assigned cells per mesh.
 
+A CPU-only tool: each cell's child process fakes 512 CPU devices. Keep it
+off the chip path — a child that reached for the TPU would find it held by
+its parent, and chip runs use one process (`chip_smoke.py`).
+
 Usage:
   PYTHONPATH=src python -m repro.launch.sweep --out results/dryrun.jsonl \
       [--mesh single|multi|both] [--arch <id> ...] [--timeout 1800]

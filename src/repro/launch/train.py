@@ -55,11 +55,14 @@ def main():
     from repro.configs import get_config, get_reduced_config
     from repro.configs.base import TrainConfig
     from repro.data import DataIterator
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.optim import adamw
     from repro.parallel import sharding as sh
     from repro.train.loop import TrainState, init_train_state, make_train_step, run_training
 
+    enable_compile_cache()
     cfg = (get_reduced_config if args.reduced else get_config)(args.arch)
     if args.qat:
         from repro.launch.dryrun import _parse_quant
@@ -72,7 +75,7 @@ def main():
         dshape = tuple(int(x) for x in args.mesh_shape.split(","))
     else:
         dshape = (n_dev, 1)
-    mesh = jax.make_mesh(dshape, ("data", "model"))
+    mesh = make_mesh(dshape, ("data", "model"))
     sh.set_mesh_context(mesh, ("data",))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}, "
           f"arch: {cfg.name} ({cfg.param_count()/1e6:.1f}M params)")

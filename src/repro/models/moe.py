@@ -73,7 +73,6 @@ def moe_apply_shardmap(params: dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.
     Requires: an active mesh context, moe_shard='expert', and
     E % |model| == 0. Falls back to moe_apply otherwise.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel import sharding as shlib
@@ -172,12 +171,12 @@ def moe_apply_shardmap(params: dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.
         down_spec = P(None, "model", None)   # (E, f/n, d) → partial sums
     else:
         up_spec = down_spec = P("model", None, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), up_spec, down_spec, up_spec, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], experts["w_up"], experts["w_down"], w_gate, x)
     return out, aux.astype(jnp.float32)
 

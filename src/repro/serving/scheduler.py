@@ -271,12 +271,20 @@ class ContinuousScheduler:
         self.on_token = on_token
 
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
-        # Fault-tolerance twin of `_decode`: a separate jit object whose
-        # trace happens inside `use("reference")`, so an injected kernel
-        # fault can re-run the SAME step on the pure-jnp reference backend
-        # (bitwise the same logits/K-V) without retracing `_decode`.
-        self._decode_ref = jax.jit(self.model.decode_step,
-                                   donate_argnums=(1,))
+
+        # Fault-tolerance twin of `_decode`, traced inside
+        # `use("reference")`, so an injected kernel fault can re-run the
+        # SAME step on the pure-jnp reference backend (bitwise the same
+        # logits/K-V). It wraps its own function: two jits of one function
+        # share a trace, and would run whichever backend traced first.
+        decode_step = self.model.decode_step
+
+        def decode_on_reference(params, cache, tokens):
+            from repro.kernels import get_registry
+            with get_registry().use("reference"):
+                return decode_step(params, cache, tokens)
+
+        self._decode_ref = jax.jit(decode_on_reference, donate_argnums=(1,))
         self._scatter = jax.jit(scatter_into_slot, donate_argnums=(0,))
         self._scatter_paged = jax.jit(scatter_into_paged, donate_argnums=(0,))
         self._prefill_cache = {}
@@ -897,10 +905,8 @@ class ContinuousScheduler:
                                               jnp.asarray(cur))
         except InjectedFault:
             self.kernel_fallbacks += 1
-            from repro.kernels import get_registry
-            with get_registry().use("reference"):
-                self.cache, logits = self._decode_ref(params, self.cache,
-                                                      jnp.asarray(cur))
+            self.cache, logits = self._decode_ref(params, self.cache,
+                                                  jnp.asarray(cur))
         return logits
 
     def _reject_reason(self, req: Request) -> Optional[str]:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import TrainConfig
 from repro.optim import adamw
 from repro.parallel import collectives
+from repro.parallel import sharding as shlib
 
 
 class TrainState(NamedTuple):
@@ -27,6 +28,30 @@ class TrainState(NamedTuple):
 def init_train_state(params, tc: TrainConfig) -> TrainState:
     err = collectives.init_error(params) if tc.grad_compress_bits else None
     return TrainState(params=params, opt=adamw.init_state(params), err=err)
+
+
+def train_state_shardings(params_shape, tc: TrainConfig, mesh,
+                          fsdp: bool) -> TrainState:
+    """NamedSharding tree of a TrainState on `mesh`: the parameters, both
+    Adam moments and the error-feedback buffers follow the partition
+    rules (`parallel.sharding`); the step count is replicated."""
+    pshard = shlib.make_param_shardings(params_shape, mesh, fsdp)
+    return TrainState(
+        params=pshard,
+        opt=adamw.AdamState(step=shlib.replicated(mesh), mu=pshard,
+                            nu=pshard),
+        err=pshard if tc.grad_compress_bits else None,
+    )
+
+
+def init_sharded_train_state(model, tc: TrainConfig, mesh, key) -> TrainState:
+    """Initialize the train state directly in its sharded layout: each
+    device materializes only its own shards, so a model whose full state
+    does not fit one device still starts."""
+    shardings = train_state_shardings(jax.eval_shape(model.init, key), tc,
+                                      mesh, model.cfg.fsdp)
+    return jax.jit(lambda k: init_train_state(model.init(k), tc),
+                   out_shardings=shardings)(key)
 
 
 def make_train_step(model, tc: TrainConfig) -> Callable:
@@ -144,13 +169,20 @@ def run_training(
     """End-to-end training with restore-if-present, periodic + preemption
     checkpoints, and straggler monitoring. Returns (state, history)."""
     init_key = init_key if init_key is not None else jax.random.PRNGKey(tc.seed)
+    mesh = shlib.get_mesh()
     start_step = 0
     if checkpoint_mgr is not None and checkpoint_mgr.latest_step() is not None:
         state, data_state, start_step = checkpoint_mgr.restore(
             lambda: init_train_state(model.init(init_key), tc)
         )
+        if mesh is not None:
+            state = jax.device_put(state, train_state_shardings(
+                jax.eval_shape(model.init, init_key), tc, mesh,
+                model.cfg.fsdp))
         if data_state is not None and hasattr(data_iter, "set_state"):
             data_iter.set_state(data_state)
+    elif mesh is not None:
+        state = init_sharded_train_state(model, tc, mesh, init_key)
     else:
         state = init_train_state(model.init(init_key), tc)
 

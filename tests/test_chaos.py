@@ -133,6 +133,28 @@ def test_kernel_fault_falls_back_bit_identically(olmo):
         assert r.out_tokens == clean[rid].out_tokens
 
 
+def test_kernel_fault_fallback_runs_on_reference_backend(olmo, monkeypatch):
+    """The fallback really runs the reference backend even when the
+    kernel path has already compiled the same decode step: the first
+    decode dispatch succeeds (seed 4), later ones fault."""
+    from repro.kernels import ref as kref
+
+    calls = []
+    real = kref.paged_attention_ref
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(kref, "paged_attention_ref", counted)
+    cfg, params = olmo
+    sched, done = _serve(
+        cfg, params, FaultInjector(4, p_kernel=0.5, max_faults=2))
+    assert sched.kernel_fallbacks == 2
+    assert calls, "the fallback reused the kernel backend's trace"
+    assert all(r.error is None for r in done.values())
+
+
 def test_nan_fault_fails_only_poisoned_request(olmo):
     cfg, params = olmo
     _, clean = _serve(cfg, params)

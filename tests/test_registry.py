@@ -1,7 +1,5 @@
 """Kernel backend registry: dispatch, scoped selection, block-plan cache,
-small-shape plan fixes, and the deprecated set_interpret shim."""
-import warnings
-
+and small-shape plan fixes."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -69,21 +67,6 @@ def test_reference_backend_end_to_end_ops():
         acc = ops.bitplane_matmul(q, w, a_bits=4)
         np.testing.assert_array_equal(
             np.asarray(acc), np.asarray(q).astype(np.int64) @ np.asarray(w))
-
-
-def test_set_interpret_is_deprecated_shim():
-    reg = get_registry()
-    before = reg.active.name
-    try:
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            ops.set_interpret(False)
-        assert any(issubclass(w.category, DeprecationWarning) for w in rec)
-        assert reg.active.name == "mosaic"
-        ops.set_interpret(True)
-        assert reg.active.name == "interpret"
-    finally:
-        reg._active = None if before == reg.default_name() else before
 
 
 # -- block plans ------------------------------------------------------------

@@ -64,8 +64,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, sys
 sys.path.insert(0, "SRC")
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as sh
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 s = sh.batch_sharding(mesh, jax.ShapeDtypeStruct((1, 8), jnp.int32), ("data",))
 assert s.spec == jax.sharding.PartitionSpec(), s.spec
 s = sh.batch_sharding(mesh, jax.ShapeDtypeStruct((4, 8), jnp.int32), ("data",))
@@ -91,6 +92,7 @@ from repro.models import build_model
 from repro.parallel import sharding as sh
 from repro.train.loop import init_train_state, make_train_step
 from repro.data import DataIterator
+from repro.launch.mesh import make_mesh
 
 cfg = get_reduced_config("olmo-1b")
 model = build_model(cfg)
@@ -102,7 +104,7 @@ batch = jax.tree_util.tree_map(jnp.asarray,
 step = make_train_step(model, tc)
 _, m_ref = step(state, batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 sh.set_mesh_context(mesh, ("data",))
 pshard = sh.make_param_shardings(params, mesh, fsdp=True)
 from repro.optim import adamw
@@ -120,5 +122,39 @@ print("OK", float(m["loss"]), float(m_ref["loss"]))
 """.replace("SRC", str(REPO / "src"))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "OK" in r.stdout
+
+
+def test_train_state_is_born_sharded():
+    """init_sharded_train_state gives every device only its shards of the
+    parameters and Adam moments, with the values of the unsharded init."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np, sys
+sys.path.insert(0, "SRC")
+from repro.configs import get_reduced_config
+from repro.configs.base import TrainConfig
+from repro.launch.mesh import make_mesh
+from repro.models import build_model
+from repro.train.loop import init_sharded_train_state, init_train_state
+
+cfg = get_reduced_config("olmo-1b")
+model = build_model(cfg)
+tc = TrainConfig()
+key = jax.random.PRNGKey(0)
+want = init_train_state(model.init(key), tc)
+state = init_sharded_train_state(model, tc, make_mesh((2, 2), ("data", "model")), key)
+emb = state.params["embed"]
+assert len(emb.addressable_shards) == 4, emb.sharding
+assert all(s.data.size == emb.size // 4 for s in emb.addressable_shards), emb.sharding
+assert state.opt.mu["embed"].sharding == emb.sharding
+for a, b in zip(jax.tree_util.tree_leaves(state), jax.tree_util.tree_leaves(want)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("OK")
+""".replace("SRC", str(REPO / "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK" in r.stdout

@@ -1,0 +1,100 @@
+"""Mosaic compiles of the served kernels at olmo-1b widths, for a described
+TPU v5e chip (``v5e:2x2``): nothing runs, the TPU compiler only has to
+accept each kernel. Interpret mode cannot show what Mosaic refuses (an
+unsupported gather, a slice of a loaded value); these tests do.
+
+All chip compiles live in this one file. The topology is described inside
+a module-scoped fixture, never at import, so every pytest worker collects
+the same tests and only the worker that runs this file loads the TPU
+library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+CFG = get_config("olmo-1b")
+NKV, NQ, H = CFG.n_kv_heads, CFG.n_heads, CFG.head_dim
+BS = 16             # serve.py's default pool block size
+POOL_BLOCKS = 512
+MAX_BLOCKS = 64     # 1024 tokens of context per row
+BATCH = 4           # serve.py's default max batch
+CHUNK = 32          # serve.py's default prefill budget
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A SingleDeviceSharding on chip 0 of a described v5e:2x2, with JAX's
+    persistent compilation cache off: a compile for a described chip is
+    written to the cache but cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler / library lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _pool(int8: bool):
+    dt = jnp.int8 if int8 else jnp.bfloat16
+    pool = [((POOL_BLOCKS, BS, NKV, H), dt)] * 2
+    scales = [((POOL_BLOCKS, BS, NKV, 1), jnp.float32)] * 2 if int8 else []
+    return pool, scales
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_compiles(chip, int8):
+    pool, scales = _pool(int8)
+
+    def fn(q, k, v, table, pos, *sc):
+        ks, vs = sc if sc else (None, None)
+        return ops.paged_attention(q, k, v, table, pos, k_scale=ks,
+                                   v_scale=vs, backend="mosaic")
+
+    _compile(fn, chip, ((BATCH, 1, NQ, H), jnp.bfloat16), *pool,
+             ((BATCH, MAX_BLOCKS), jnp.int32), ((BATCH,), jnp.int32),
+             *scales)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_prefill_compiles(chip, int8):
+    pool, scales = _pool(int8)
+
+    def fn(q, kn, vn, k, v, blocks, start, length, *sc):
+        ks, vs = sc if sc else (None, None)
+        return ops.paged_prefill(q, kn, vn, k, v, blocks, start, length,
+                                 k_scale=ks, v_scale=vs, backend="mosaic")
+
+    chunk = ((1, CHUNK, NKV, H), jnp.bfloat16)
+    _compile(fn, chip, ((1, CHUNK, NQ, H), jnp.bfloat16), chunk, chunk,
+             *pool, ((MAX_BLOCKS,), jnp.int32), ((), jnp.int32),
+             ((), jnp.int32), *scales)
+
+
+def test_fused_quantize_matmul_compiles(chip):
+    # The FFN down-projection: K = d_ff = 8192, N = d_model = 2048.
+    fn = functools.partial(ops.fused_quantize_matmul, a_bits=8,
+                           backend="mosaic")
+    _compile(fn, chip, ((CHUNK, CFG.d_ff), jnp.float32),
+             ((CFG.d_ff, CFG.d_model), jnp.int8))
