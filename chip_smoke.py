@@ -64,28 +64,20 @@ def check(cond, msg: str) -> None:
 
 @contextlib.contextmanager
 def compile_seconds():
-    """Sum JAX's backend-compile durations (a persistent-cache hit counts
-    as its retrieval time) inside the block; yields a dict it fills."""
-    import jax
+    """JAX's backend compiles inside the block, from the program's own
+    counter (`repro.serving.compiles`; a persistent-cache hit counts as a
+    compile of its retrieval time); yields a dict it fills."""
+    from repro.serving import compiles
 
     out = {"seconds": 0.0, "compiles": 0, "cache_hits": 0}
-
-    def on_duration(event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            out["seconds"] += secs
-            out["compiles"] += 1
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            out["cache_hits"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
+    before = compiles.snapshot()
     try:
         yield out
     finally:
-        jax.monitoring.unregister_event_duration_listener(on_duration)
-        jax.monitoring.unregister_event_listener(on_event)
+        after = compiles.snapshot()
+        out["seconds"] = after["compile_seconds"] - before["compile_seconds"]
+        out["compiles"] = after["compiles"] - before["compiles"]
+        out["cache_hits"] = after["cache_hits"] - before["cache_hits"]
 
 
 @contextlib.contextmanager

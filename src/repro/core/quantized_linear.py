@@ -153,10 +153,12 @@ def qmatmul(
     raise ValueError(f"unknown qmatmul mode {mode!r}")
 
 
+@jax.named_scope("serve_matmul")
 def _serve_matmul(
     x: jax.Array, pw: PackedWeight, cfg: Optional[QuantConfig], use_kernel: bool
 ) -> jax.Array:
-    """Packed-weight matmul.
+    """Packed-weight matmul, in the ``serve_matmul`` device scope (both
+    paths), which the profiler trace attributes its device time by.
 
     Activation precision comes from `cfg` when given, else from the
     PackedWeight leaf itself — which is how a per-layer PrecisionPolicy

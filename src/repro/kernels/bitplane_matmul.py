@@ -169,6 +169,7 @@ def bitplane_matmul(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        name="bitplane_matmul",
         interpret=interpret,
     )(x, w)
     return out[:m, :n]

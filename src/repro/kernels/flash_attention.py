@@ -132,6 +132,7 @@ def flash_attention(
         out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
         scratch_shapes=scratch,
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :Tq]

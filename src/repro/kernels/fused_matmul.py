@@ -176,6 +176,7 @@ def fused_quantize_matmul(
             jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         ],
         compiler_params=compiler_params(("parallel", "parallel", "arbitrary")),
+        name="fused_quantize_matmul",
         interpret=interpret,
     )(xp, wp)
     return acc[:m, :n], scales[:m]

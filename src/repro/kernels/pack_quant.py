@@ -70,6 +70,7 @@ def quantize_rows(
             jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(("parallel",)),
+        name="quantize_rows",
         interpret=interpret,
     )(xp)
     return q[:m], s[:m]

@@ -184,6 +184,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NKV, G, H), q.dtype),
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(block_table, q_pos, *operands)
     return out.reshape(B, 1, NQ, H)

@@ -295,6 +295,7 @@ def paged_prefill_attention(
         out_shape=out_shapes,
         compiler_params=_compiler_params(("arbitrary", "arbitrary")),
         input_output_aliases=aliases,
+        name="paged_prefill",
         interpret=interpret,
     )(blocks, meta, *operands)
     attn = outs[0].reshape(NKV, Lc, G, H).transpose(1, 0, 2, 3)

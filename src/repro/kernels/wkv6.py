@@ -121,6 +121,7 @@ def wkv6(
         out_shape=jax.ShapeDtypeStruct((H, Tp, V), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         compiler_params=_compiler_params(("arbitrary", "arbitrary")),
+        name="wkv6",
         interpret=interpret,
     )(rt, kt, vt, wt, u[:, None, :])
     return jnp.swapaxes(out, 0, 1)[:T]

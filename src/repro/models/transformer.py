@@ -98,11 +98,12 @@ def _attention_qkv(p, cfg: ModelConfig, x, positions):
     q = cm.linear(x, p["wq"], q_cfg, qm).reshape(B, T, cfg.n_heads, hd)
     k = cm.linear(x, p["wk"], q_cfg, qm).reshape(B, T, cfg.n_kv_heads, hd)
     v = cm.linear(x, p["wv"], q_cfg, qm).reshape(B, T, cfg.n_kv_heads, hd)
-    if cfg.qk_norm:
-        q = cm.rms_head_norm(q, p["q_norm"])
-        k = cm.rms_head_norm(k, p["k_norm"])
-    q = cm.rope(q, positions, cfg.rope_theta)
-    k = cm.rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        if cfg.qk_norm:
+            q = cm.rms_head_norm(q, p["q_norm"])
+            k = cm.rms_head_norm(k, p["k_norm"])
+        q = cm.rope(q, positions, cfg.rope_theta)
+        k = cm.rope(k, positions, cfg.rope_theta)
     from repro.parallel.sharding import axis_size
 
     if cfg.attn_shard == "heads" and cfg.n_heads % max(axis_size("model"), 1) == 0:
@@ -207,11 +208,12 @@ def block_decode(
             k_cache, v_cache, slot_pos, k, v, pos, cfg.attn_window
         )
         k_scale = v_scale = None  # ignore dummy scan placeholders
-    attn = cm.decode_attention(
-        q, k_cache, v_cache, slot_pos, pos,
-        window=cfg.attn_window, softcap=cfg.attn_logit_softcap,
-        k_scale=k_scale, v_scale=v_scale,
-    )
+    with jax.named_scope("attention"):
+        attn = cm.decode_attention(
+            q, k_cache, v_cache, slot_pos, pos,
+            window=cfg.attn_window, softcap=cfg.attn_logit_softcap,
+            k_scale=k_scale, v_scale=v_scale,
+        )
     x = _block_post_attn(p, cfg, x, attn)
     return x, k_cache, v_cache, slot_pos, k_scale, v_scale
 
@@ -257,23 +259,24 @@ def block_decode_paged(
         pool_k, pool_v, block_table, k, v, pos, block_size,
         k_scale=k_scale, v_scale=v_scale,
     )
-    if fused:
-        from repro.kernels import ops
+    with jax.named_scope("attention"):
+        if fused:
+            from repro.kernels import ops
 
-        attn = ops.paged_attention(
-            q, pool_k, pool_v, block_table, pos,
-            k_scale=k_scale, v_scale=v_scale,
-            softcap=cfg.attn_logit_softcap,
-        )
-    else:
-        k_rows, v_rows, kpos, ks_rows, vs_rows = paged_gather(
-            pool_k, pool_v, block_table, k_scale, v_scale,
-            max_blocks=gather_blocks,
-        )
-        attn = cm.decode_attention(
-            q, k_rows, v_rows, kpos, pos, softcap=cfg.attn_logit_softcap,
-            k_scale=ks_rows, v_scale=vs_rows,
-        )
+            attn = ops.paged_attention(
+                q, pool_k, pool_v, block_table, pos,
+                k_scale=k_scale, v_scale=v_scale,
+                softcap=cfg.attn_logit_softcap,
+            )
+        else:
+            k_rows, v_rows, kpos, ks_rows, vs_rows = paged_gather(
+                pool_k, pool_v, block_table, k_scale, v_scale,
+                max_blocks=gather_blocks,
+            )
+            attn = cm.decode_attention(
+                q, k_rows, v_rows, kpos, pos, softcap=cfg.attn_logit_softcap,
+                k_scale=ks_rows, v_scale=vs_rows,
+            )
     return _block_post_attn(p, cfg, x, attn), pool_k, pool_v, k_scale, v_scale
 
 
@@ -353,6 +356,22 @@ def forward_hidden(params, cfg: ModelConfig, batch):
     x, positions = embed_inputs(params, cfg, batch)
     x, aux, _ = _scan_blocks(params, cfg, x, positions, _mask_for(cfg), False)
     return cm.apply_norm(x, params["final_norm"], cfg.norm), aux
+
+
+def _embed(params, cfg: ModelConfig, tokens):
+    """Token embeddings of the decode-path programs, in the ``embed``
+    device scope."""
+    with jax.named_scope("embed"):
+        return cm.embed_lookup(params["embed"], tokens,
+                               scale=_embed_scale(cfg))
+
+
+def _head(params, cfg: ModelConfig, x):
+    """Final norm and logits of the decode-path programs, in the ``head``
+    device scope."""
+    with jax.named_scope("head"):
+        return compute_logits(params, cfg,
+                              cm.apply_norm(x, params["final_norm"], cfg.norm))
 
 
 def compute_logits(params, cfg: ModelConfig, hidden):
@@ -435,9 +454,7 @@ def prefill(params, cfg: ModelConfig, batch) -> Tuple[DecodeCache, jax.Array]:
         v_scale=v_scale,
         window=w,
     )
-    hidden = cm.apply_norm(cm.last_token_slice(x, lengths),
-                           params["final_norm"], cfg.norm)
-    logits = compute_logits(params, cfg, hidden)
+    logits = _head(params, cfg, cm.last_token_slice(x, lengths))
     return DecodeCache(pos=length, kv=kvc), logits
 
 
@@ -500,7 +517,7 @@ def prefill_suffix(params, cfg: ModelConfig, batch):
     positions = jnp.broadcast_to(spos[None], (B, Ls))
     mask = cm.AttnMask(causal=cfg.causal)
 
-    x = cm.embed_lookup(params["embed"], tokens, scale=_embed_scale(cfg))
+    x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
 
     def body(xc, layer_in):
@@ -533,9 +550,7 @@ def prefill_suffix(params, cfg: ModelConfig, batch):
         slot_pos=jnp.broadcast_to(suffix_kpos[None, None], (L, B, Ls)),
         length=total, k_scale=k_scale, v_scale=v_scale, window=0,
     )
-    hidden = cm.apply_norm(cm.last_token_slice(x, lengths),
-                           params["final_norm"], cfg.norm)
-    logits = compute_logits(params, cfg, hidden)
+    logits = _head(params, cfg, cm.last_token_slice(x, lengths))
     return DecodeCache(pos=total, kv=kvc), logits
 
 
@@ -596,19 +611,20 @@ def prefill_chunk(params, cfg: ModelConfig, cache: DecodeCache, batch,
 
     spos = start + jnp.arange(Lc, dtype=jnp.int32)
     positions = jnp.broadcast_to(spos[None], (B, Lc))
-    x = cm.embed_lookup(params["embed"], tokens, scale=_embed_scale(cfg))
+    x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
 
     def body(xc, layer_in):
         block_p, pk, pv, ks, vs = layer_in
         h = cm.apply_norm(xc, block_p["ln1"], cfg.norm)
         q, k, v = _attention_qkv(block_p, cfg, h, positions)
-        attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
-            q, k, v, pk, pv, blocks, start, length,
-            k_scale=ks if quant else None,
-            v_scale=vs if quant else None,
-            softcap=cfg.attn_logit_softcap,
-        )
+        with jax.named_scope("attention"):
+            attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
+                q, k, v, pk, pv, blocks, start, length,
+                k_scale=ks if quant else None,
+                v_scale=vs if quant else None,
+                softcap=cfg.attn_logit_softcap,
+            )
         xn, _ = _block_post_attn_seq(block_p, cfg, xc, attn)
         if quant:
             ks, vs = ks_new, vs_new
@@ -619,11 +635,8 @@ def prefill_chunk(params, cfg: ModelConfig, cache: DecodeCache, batch,
     x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
         body, x, (params["blocks"], kv.k, kv.v, ks_in, vs_in)
     )
-    hidden = (cm.apply_norm(x, params["final_norm"], cfg.norm)
-              if all_logits else
-              cm.apply_norm(cm.last_token_slice(x, lengths),
-                            params["final_norm"], cfg.norm))
-    logits = compute_logits(params, cfg, hidden)
+    logits = _head(params, cfg,
+                   x if all_logits else cm.last_token_slice(x, lengths))
     total = start + length
     new_cache = DecodeCache(
         pos=cache.pos.at[slot].set(total),
@@ -702,20 +715,20 @@ def prefill_chunk_logits_multi(params, cfg: ModelConfig, cache: DecodeCache,
         pk_all, pv_all, ks_all, vs_all, pos, lng = carry
         toks_r, len_r, start_r, slot_r, blocks_r = row_in
         positions = (start_r + jnp.arange(Lc, dtype=jnp.int32))[None]
-        x = cm.embed_lookup(params["embed"], toks_r[None],
-                            scale=_embed_scale(cfg))
+        x = _embed(params, cfg, toks_r[None])
         x = constrain(x, "batch", None, None)
 
         def body(xc, layer_in):
             block_p, pk, pv, ks, vs = layer_in
             h = cm.apply_norm(xc, block_p["ln1"], cfg.norm)
             q, k, v = _attention_qkv(block_p, cfg, h, positions)
-            attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
-                q, k, v, pk, pv, blocks_r, start_r, len_r,
-                k_scale=ks if quant else None,
-                v_scale=vs if quant else None,
-                softcap=cfg.attn_logit_softcap,
-            )
+            with jax.named_scope("attention"):
+                attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
+                    q, k, v, pk, pv, blocks_r, start_r, len_r,
+                    k_scale=ks if quant else None,
+                    v_scale=vs if quant else None,
+                    softcap=cfg.attn_logit_softcap,
+                )
             xn, _ = _block_post_attn_seq(block_p, cfg, xc, attn)
             if quant:
                 ks, vs = ks_new, vs_new
@@ -724,8 +737,7 @@ def prefill_chunk_logits_multi(params, cfg: ModelConfig, cache: DecodeCache,
         x, (pk_all, pv_all, ks_all, vs_all) = jax.lax.scan(
             body, x, (params["blocks"], pk_all, pv_all, ks_all, vs_all)
         )
-        hidden = cm.apply_norm(x, params["final_norm"], cfg.norm)
-        logits = compute_logits(params, cfg, hidden)
+        logits = _head(params, cfg, x)
         total = start_r + len_r
         sc = jnp.maximum(slot_r, 0)      # .at[-1] would wrap — clamp + gate
         live = slot_r >= 0
@@ -762,7 +774,7 @@ def decode_step(params, cfg: ModelConfig, cache: DecodeCache, tokens: jax.Array,
         return _decode_step_paged(params, cfg, cache, tokens,
                                   fused=paged_fused,
                                   gather_blocks=gather_blocks)
-    x = cm.embed_lookup(params["embed"], tokens, scale=_embed_scale(cfg))
+    x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
     pos = cache.pos
 
@@ -783,8 +795,7 @@ def decode_step(params, cfg: ModelConfig, cache: DecodeCache, tokens: jax.Array,
         (params["blocks"], cache.kv.k, cache.kv.v, cache.kv.slot_pos,
          ks_in, vs_in),
     )
-    hidden = cm.apply_norm(x, params["final_norm"], cfg.norm)
-    logits = compute_logits(params, cfg, hidden)
+    logits = _head(params, cfg, x)
     new_cache = DecodeCache(
         pos=pos + 1,
         kv=KVCache(k=k_new, v=v_new, slot_pos=sp_new,
@@ -803,7 +814,7 @@ def _decode_step_paged(params, cfg: ModelConfig, cache: DecodeCache, tokens,
     any mix of slot depths and block-table layouts. `fused`/
     `gather_blocks` select the fused kernel (default) vs the clamped
     gather-then-attend reference path."""
-    x = cm.embed_lookup(params["embed"], tokens, scale=_embed_scale(cfg))
+    x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
     pos = cache.pos
     kv: PagedKVCache = cache.kv
@@ -828,8 +839,7 @@ def _decode_step_paged(params, cfg: ModelConfig, cache: DecodeCache, tokens,
     x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
         body, x, (params["blocks"], kv.k, kv.v, ks_in, vs_in)
     )
-    hidden = cm.apply_norm(x, params["final_norm"], cfg.norm)
-    logits = compute_logits(params, cfg, hidden)
+    logits = _head(params, cfg, x)
     new_cache = DecodeCache(
         pos=pos + 1,
         kv=PagedKVCache(k=k_new, v=v_new, block_table=table,

@@ -45,6 +45,8 @@ def sample_tokens(logits, temperatures, top_ks, base_keys, steps):
     base_keys (B, 2) uint32; steps (B,) int → tokens (B,) int32.
 
     temperature <= 0 means greedy for that slot (keys/steps unused there).
+    Runs in the ``sample`` device scope.
     """
-    return jax.vmap(_sample_one)(logits, temperatures, top_ks, base_keys,
-                                 steps)
+    with jax.named_scope("sample"):
+        return jax.vmap(_sample_one)(logits, temperatures, top_ks, base_keys,
+                                     steps)

@@ -83,6 +83,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.precision import (
@@ -1922,47 +1923,52 @@ class ContinuousScheduler:
         resident: the row's full blocks are registered in the prefix
         index, the device table is unmasked, and the first output token is
         sampled from the chunk's last-token logits. Returns the request
-        if it finished on that first token."""
+        if it finished on that first token. Runs in the ``serving.chunk``
+        profiler span (chunk ``start`` and ``len``, ``plans`` in flight)."""
         plan = self._chunk_plans[slot]
         req, n, start = plan["req"], plan["n"], plan["next"]
         Lc = self.prefill_budget
         t = min(Lc, n - start)
-        tokens = np.zeros((1, Lc), np.int32)
-        tokens[0, :t] = plan["toks"][start:start + t]
-        # Clamp the kernel's block-table operand to the blocks covering
-        # [0, start + t), bucketed like _prefill_suffix's gather clamp so
-        # the compiled signature count stays bounded: one executable per
-        # (budget, bucketed covering-blocks) pair.
-        gran = max(self.bucket // self.block_size, 1)
-        covering = -(-(start + t) // self.block_size)
-        nbp = min(self._max_blocks, max(gran, -(-covering // gran) * gran))
-        batch = {
-            "tokens": jnp.asarray(tokens),
-            "lengths": jnp.asarray([t], jnp.int32),
-            "start": jnp.asarray(start, jnp.int32),
-            "slot": jnp.asarray(slot, jnp.int32),
-            "blocks": jnp.asarray(self._block_tab[slot, :nbp]),
-        }
-        self.cache, logits = self._chunk(
-            self._tier_views[self._slot_tier[slot]], self.cache, batch)
-        self.prefill_chunks_run += 1
-        self.prefill_chunk_tokens += t
-        self.prefill_tokens_computed += Lc
-        plan["next"] = start + t
-        if self.prefix_cache:
-            # Blocks this chunk fully covered are final — index them now
-            # so a same-prefix request admitted on a later step shares
-            # them instead of re-prefilling.
-            self._register_full(slot, limit=plan["next"] // self.block_size)
-        if plan["next"] < n:
-            return None
-        # Prompt fully resident: the slot graduates to decoding.
-        del self._chunk_plans[slot]
-        self._pos_host[slot] = n
-        if self.prefix_cache:
-            self._register_full(slot)
-        self._table_dirty = True       # unmask the row for the decode step
-        return self._first_token(req, slot, logits)
+        with TraceAnnotation("serving.chunk", start=start, len=t,
+                             plans=len(self._chunk_plans)):
+            tokens = np.zeros((1, Lc), np.int32)
+            tokens[0, :t] = plan["toks"][start:start + t]
+            # Clamp the kernel's block-table operand to the blocks
+            # covering [0, start + t), bucketed like _prefill_suffix's
+            # gather clamp so the compiled signature count stays bounded:
+            # one executable per (budget, bucketed covering-blocks) pair.
+            gran = max(self.bucket // self.block_size, 1)
+            covering = -(-(start + t) // self.block_size)
+            nbp = min(self._max_blocks,
+                      max(gran, -(-covering // gran) * gran))
+            batch = {
+                "tokens": jnp.asarray(tokens),
+                "lengths": jnp.asarray([t], jnp.int32),
+                "start": jnp.asarray(start, jnp.int32),
+                "slot": jnp.asarray(slot, jnp.int32),
+                "blocks": jnp.asarray(self._block_tab[slot, :nbp]),
+            }
+            self.cache, logits = self._chunk(
+                self._tier_views[self._slot_tier[slot]], self.cache, batch)
+            self.prefill_chunks_run += 1
+            self.prefill_chunk_tokens += t
+            self.prefill_tokens_computed += Lc
+            plan["next"] = start + t
+            if self.prefix_cache:
+                # Blocks this chunk fully covered are final — index them
+                # now so a same-prefix request admitted on a later step
+                # shares them instead of re-prefilling.
+                self._register_full(slot,
+                                    limit=plan["next"] // self.block_size)
+            if plan["next"] < n:
+                return None
+            # Prompt fully resident: the slot graduates to decoding.
+            del self._chunk_plans[slot]
+            self._pos_host[slot] = n
+            if self.prefix_cache:
+                self._register_full(slot)
+            self._table_dirty = True   # unmask the row for the decode step
+            return self._first_token(req, slot, logits)
 
     def _emit(self, req: Request, tok: int) -> None:
         """Count the token and stream it to the per-request and scheduler-
@@ -2214,9 +2220,125 @@ class ContinuousScheduler:
         pressure are served at the cheapest configured tier. Returns the
         requests that finished this step (including any rejected as
         oversized, cancelled, past deadline, or individually failed —
-        those carry ``error``)."""
+        those carry ``error``).
+
+        Each phase runs in a ``serving.*`` profiler span (see `_step`);
+        with no profiler running a span costs about a microsecond."""
         self._step_calls += 1
-        finished: List[Request] = list(self._lifecycle_phase())
+        with TraceAnnotation("serving.step", step=self._step_calls):
+            return self._step()
+
+    def _step(self) -> List[Request]:
+        """The body of `step()`, one profiler span per phase:
+        ``serving.lifecycle``, ``serving.admit``, ``serving.chunk`` (in
+        `_run_chunk`), ``serving.spec``, ``serving.table_sync``,
+        ``serving.decode``, ``serving.nan_check``, ``serving.sample`` and
+        ``serving.emit``. Span arguments come from host state only."""
+        with TraceAnnotation("serving.lifecycle"):
+            finished: List[Request] = list(self._lifecycle_phase())
+        with TraceAnnotation("serving.admit"):
+            self._admission_phase(finished)
+
+        # Spend one budgeted chunk of admission prefill alongside this
+        # step's decode — round-robin across queued plans: the serviced
+        # plan rotates to the back, so with several admissions in flight
+        # each spends one chunk every len(queue) steps and no prompt's
+        # first token waits for every earlier prompt to finish prefilling.
+        chunk_ran = False
+        if self._chunk_queue:
+            slot = self._chunk_queue.popleft()
+            chunk_ran = True
+            done = self._run_chunk(slot)
+            if slot in self._chunk_plans:
+                self._chunk_queue.append(slot)  # unfinished: back of line
+            elif done is not None:
+                finished.append(done)
+            self.prefill_chunk_steps += 1
+
+        if not any(r is not None and b not in self._chunk_plans
+                   for b, r in enumerate(self._slots)):
+            return finished  # nothing decodes: only chunk plans in flight
+
+        if self.speculate:
+            # Speculation rounds replace several sequential decode steps
+            # for greedy slots; survivors still join the trailing decode
+            # below, which is exactly their next sequential step.
+            with TraceAnnotation("serving.spec"):
+                finished.extend(self._spec_phase())
+            if not any(r is not None and b not in self._chunk_plans
+                       for b, r in enumerate(self._slots)):
+                return finished  # every live slot retired mid-round
+
+        if chunk_ran:
+            self.decode_steps_stalled += 1
+        if self.paged:
+            with TraceAnnotation("serving.table_sync"):
+                self._alloc_boundary_blocks()
+                self._sync_table()
+        groups: Dict[Optional[str], List[int]] = {}
+        for b, r in enumerate(self._slots):
+            if r is not None and b not in self._chunk_plans:
+                groups.setdefault(self._slot_tier[b], []).append(b)
+        live = sorted(b for g in groups.values() for b in g)
+        with TraceAnnotation(
+                "serving.decode", rows=len(live),
+                keys=int(self._pos_host[live].sum()) + len(live)):
+            if len(groups) <= 1:
+                # Homogeneous batch (incl. the no-tiers engine): one decode
+                # with the group's view params — exactly what a solo
+                # engine whose whole policy is this tier runs, so
+                # bit-identity for the single-tier case holds by
+                # construction.
+                key = next(iter(groups), None)
+                logits = self._decode_call(self._tier_views[key], self._cur)
+                self.tier_counters[key]["decode_calls"] += 1
+                last = logits[:, -1, :]
+            else:
+                last = self._decode_tier_groups(groups)
+        if self.chaos is not None and live and self.chaos.fire("nan"):
+            # Chaos: poison one live row's logits; the detector below
+            # must catch it and fail that request alone.
+            bad_row = live[self.chaos.pick(len(live))]
+            last = jnp.asarray(last).at[bad_row].set(jnp.nan)
+        # Always-on poisoned-logits detector: a non-finite logits row
+        # (numerics blow-up, corrupted weights, injected fault) cannot
+        # sample a meaningful token — retire just that request with
+        # error="nan-logits" before sampling; its K/V writes this step
+        # were row-local, so batch neighbours are untouched.
+        with TraceAnnotation("serving.nan_check"):
+            bad = np.asarray(jnp.any(
+                ~jnp.isfinite(jnp.asarray(last).astype(jnp.float32)),
+                axis=-1))
+        for b in live:
+            if bad[b]:
+                self.nan_logit_events += 1
+                finished.append(self._retire_abnormal(b, "nan-logits"))
+        with TraceAnnotation("serving.sample"):
+            toks = np.asarray(sampling.sample_tokens(
+                last, self._temps, self._top_ks,
+                self._keys, self._steps,
+            ))
+        self._steps += 1
+        self.steps_run += 1
+        with TraceAnnotation("serving.emit"):
+            for b, req in enumerate(self._slots):
+                if req is None or b in self._chunk_plans:
+                    continue  # mid-chunk-prefill slots don't sample yet
+                self._pos_host[b] += 1
+                tok = int(toks[b])
+                req.out_tokens.append(tok)
+                self._emit(req, tok)
+                if self._finished(req, tok):
+                    self._release_slot(b)
+                    finished.append(req)
+                else:
+                    self._cur[b, 0] = tok
+        return finished
+
+    def _admission_phase(self, finished: List[Request]) -> None:
+        """Admit waiting requests into free slots for one step (see
+        `step()`); rejected requests and those that finish on their
+        prefill token go onto `finished`."""
         pressure = False
         chunk_admitted = False
         preempted = False
@@ -2301,92 +2423,6 @@ class ContinuousScheduler:
             free.popleft()
         self._pressure_streak = self._pressure_streak + 1 if pressure else 0
         self.queue_wait_steps += len(self.waiting)
-
-        # Spend one budgeted chunk of admission prefill alongside this
-        # step's decode — round-robin across queued plans: the serviced
-        # plan rotates to the back, so with several admissions in flight
-        # each spends one chunk every len(queue) steps and no prompt's
-        # first token waits for every earlier prompt to finish prefilling.
-        chunk_ran = False
-        if self._chunk_queue:
-            slot = self._chunk_queue.popleft()
-            chunk_ran = True
-            done = self._run_chunk(slot)
-            if slot in self._chunk_plans:
-                self._chunk_queue.append(slot)  # unfinished: back of line
-            elif done is not None:
-                finished.append(done)
-            self.prefill_chunk_steps += 1
-
-        if not any(r is not None and b not in self._chunk_plans
-                   for b, r in enumerate(self._slots)):
-            return finished  # nothing decodes: only chunk plans in flight
-
-        if self.speculate:
-            # Speculation rounds replace several sequential decode steps
-            # for greedy slots; survivors still join the trailing decode
-            # below, which is exactly their next sequential step.
-            finished.extend(self._spec_phase())
-            if not any(r is not None and b not in self._chunk_plans
-                       for b, r in enumerate(self._slots)):
-                return finished  # every live slot retired mid-round
-
-        if chunk_ran:
-            self.decode_steps_stalled += 1
-        if self.paged:
-            self._alloc_boundary_blocks()
-            self._sync_table()
-        groups: Dict[Optional[str], List[int]] = {}
-        for b, r in enumerate(self._slots):
-            if r is not None and b not in self._chunk_plans:
-                groups.setdefault(self._slot_tier[b], []).append(b)
-        if len(groups) <= 1:
-            # Homogeneous batch (incl. the no-tiers engine): one decode
-            # with the group's view params — exactly what a solo engine
-            # whose whole policy is this tier runs, so bit-identity for
-            # the single-tier case holds by construction.
-            key = next(iter(groups), None)
-            logits = self._decode_call(self._tier_views[key], self._cur)
-            self.tier_counters[key]["decode_calls"] += 1
-            last = logits[:, -1, :]
-        else:
-            last = self._decode_tier_groups(groups)
-        live = sorted(b for g in groups.values() for b in g)
-        if self.chaos is not None and live and self.chaos.fire("nan"):
-            # Chaos: poison one live row's logits; the detector below
-            # must catch it and fail that request alone.
-            bad_row = live[self.chaos.pick(len(live))]
-            last = jnp.asarray(last).at[bad_row].set(jnp.nan)
-        # Always-on poisoned-logits detector: a non-finite logits row
-        # (numerics blow-up, corrupted weights, injected fault) cannot
-        # sample a meaningful token — retire just that request with
-        # error="nan-logits" before sampling; its K/V writes this step
-        # were row-local, so batch neighbours are untouched.
-        bad = np.asarray(jnp.any(
-            ~jnp.isfinite(jnp.asarray(last).astype(jnp.float32)), axis=-1))
-        for b in live:
-            if bad[b]:
-                self.nan_logit_events += 1
-                finished.append(self._retire_abnormal(b, "nan-logits"))
-        toks = np.asarray(sampling.sample_tokens(
-            last, self._temps, self._top_ks,
-            self._keys, self._steps,
-        ))
-        self._steps += 1
-        self.steps_run += 1
-        for b, req in enumerate(self._slots):
-            if req is None or b in self._chunk_plans:
-                continue  # mid-chunk-prefill slots don't sample yet
-            self._pos_host[b] += 1
-            tok = int(toks[b])
-            req.out_tokens.append(tok)
-            self._emit(req, tok)
-            if self._finished(req, tok):
-                self._release_slot(b)
-                finished.append(req)
-            else:
-                self._cur[b, 0] = tok
-        return finished
 
     def run(self, requests=()) -> List[Request]:
         """Serve a workload to completion, admitting each request no

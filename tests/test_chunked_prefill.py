@@ -182,3 +182,127 @@ def test_engine_threads_knobs(olmo):
     out2 = eng2.generate([Request(0, PROMPT_LONG, max_new_tokens=6)])
     assert out2[0].out_tokens == out
     assert eng2._sched.prefill_chunks_run == 0
+
+
+PHASES = ("lifecycle", "admit", "chunk", "table_sync", "decode",
+          "nan_check", "sample", "emit")
+
+
+def _trace_spans(trace_dir) -> list:
+    """(name, start_ns, end_ns, args) of the `serving.*` profiler spans in
+    a CPU trace, in time order."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    events = [e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("serving.")]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for e in sorted(events, key=lambda e: e.start_ns)]
+
+
+def _traced_drain(sched, trace_dir):
+    """Drain `sched` under the profiler: (finished requests, spans)."""
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        done = _drain(sched)
+    finally:
+        jax.profiler.stop_trace()
+    return done, _trace_spans(str(trace_dir))
+
+
+@pytest.fixture(scope="module")
+def traced(olmo, tmp_path_factory):
+    """A long and a short prompt chunk-prefilled at budget 8 and decoded,
+    under the profiler: (finished requests, spans)."""
+    cfg, params = olmo
+    sched = _sched(cfg, params, 8)
+    sched.submit(Request(0, PROMPT_LONG, max_new_tokens=4))
+    sched.submit(Request(1, PROMPT_SHORT, max_new_tokens=3))
+    return _traced_drain(sched, tmp_path_factory.mktemp("trace"))
+
+
+def test_step_phases_are_traced(traced):
+    """Each phase of `step()` leaves a `serving.*` span: the chunk spans
+    carry the chunks' starts and lengths, the decode spans' rows add up
+    to the tokens decoded, and the step spans number the steps."""
+    done, spans = traced
+    names = {sp[0] for sp in spans}
+    assert {"serving.step"} | {"serving." + p for p in PHASES} <= names
+    chunks = [(a["start"], a["len"]) for n, _, _, a in spans
+              if n == "serving.chunk"]
+    assert sorted(chunks) == [(0, 8), (0, 8), (8, 8), (16, 7)]
+    assert all(a["plans"] >= 1 for n, _, _, a in spans
+               if n == "serving.chunk")
+    rows = [a["rows"] for n, _, _, a in spans if n == "serving.decode"]
+    # every token after a request's first comes from a decode row
+    assert sum(rows) == sum(len(r.out_tokens) - 1 for r in done) > 0
+    steps = [a for n, _, _, a in spans if n == "serving.step"]
+    assert [a["step"] for a in steps] == list(range(1, len(steps) + 1))
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_phase_spans_nest_in_steps(traced, phase):
+    """Every span of a phase lies inside one `serving.step` span, and a
+    step opens it at most once: one span per phase, never one per row or
+    token. Retirement and admission run in every step."""
+    _, spans = traced
+    steps = [sp for sp in spans if sp[0] == "serving.step"]
+    per_step = [0] * len(steps)
+    for name, s, e, _ in spans:
+        if name != "serving." + phase:
+            continue
+        owners = [i for i, st in enumerate(steps) if st[1] <= s and e <= st[2]]
+        assert len(owners) == 1
+        per_step[owners[0]] += 1
+    assert max(per_step) == 1
+    if phase in ("lifecycle", "admit"):
+        assert min(per_step) == 1
+
+
+def test_tracing_leaves_tokens_unchanged(olmo, traced):
+    """The same requests served with no profiler running give the same
+    tokens: the spans read host state only and change no device work."""
+    cfg, params = olmo
+    sched = _sched(cfg, params, 8)
+    sched.submit(Request(0, PROMPT_LONG, max_new_tokens=4))
+    sched.submit(Request(1, PROMPT_SHORT, max_new_tokens=3))
+    untraced = {r.rid: r.out_tokens for r in _drain(sched)}
+    assert untraced == {r.rid: r.out_tokens for r in traced[0]}
+
+
+def test_speculation_round_is_traced(olmo, tmp_path):
+    """With speculation on, each round runs in a `serving.spec` span
+    inside its step, ahead of that step's trailing decode."""
+    from repro.core.precision import QuantConfig
+
+    cfg, params = olmo
+    sched = _sched(cfg, params, 8, quant=QuantConfig(w_bits=8, a_bits=8),
+                   speculate=2, draft_policy="w4a8")
+    sched.submit(Request(0, PROMPT_SHORT, max_new_tokens=6))
+    _, spans = _traced_drain(sched, tmp_path)
+    steps = [sp for sp in spans if sp[0] == "serving.step"]
+    specs = [sp for sp in spans if sp[0] == "serving.spec"]
+    assert specs and sched.spec_verify_calls > 0
+    for _, s, e, _ in specs:
+        step = [st for st in steps if st[1] <= s and e <= st[2]]
+        assert len(step) == 1
+        decodes = [sp for sp in spans if sp[0] == "serving.decode"
+                   and step[0][1] <= sp[1] <= step[0][2]]
+        assert all(d[1] >= e for d in decodes)
+
+
+def test_compile_counter_counts_backend_compiles():
+    """`repro.serving.compiles` counts every backend compile in the
+    process, with its seconds; a fresh compile moves both."""
+    from repro.serving import compiles
+
+    before = compiles.snapshot()
+    jax.jit(lambda x: x * 3 + 1)(np.ones(7, np.float32)).block_until_ready()
+    after = compiles.snapshot()
+    assert after["compiles"] >= before["compiles"] + 1
+    assert after["compile_seconds"] > before["compile_seconds"]

@@ -9,6 +9,8 @@ the same tests and only the worker that runs this file loads the TPU
 library.
 """
 import functools
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ POOL_BLOCKS = 512
 MAX_BLOCKS = 64     # 1024 tokens of context per row
 BATCH = 4           # serve.py's default max batch
 CHUNK = 32          # serve.py's default prefill budget
+ROOFLINE = Path(__file__).resolve().parents[1] / "bench" / "roofline"
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +57,7 @@ def _compile(fn, chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _pool(int8: bool):
@@ -98,3 +102,35 @@ def test_fused_quantize_matmul_compiles(chip):
                            backend="mosaic")
     _compile(fn, chip, ((CHUNK, CFG.d_ff), jnp.float32),
              ((CFG.d_ff, CFG.d_model), jnp.int8))
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "paged_prefill"])
+def test_kernel_names_reach_the_compiled_program(chip, kernel):
+    """The device operation of each served attention kernel is named for
+    its `pallas_call`, and carries every name the benchmark's roofline
+    reader for that kernel matches (`bench/roofline/<kernel>.py` NAMES)."""
+    pool, scales = _pool(True)
+    if kernel == "paged_attention":
+        def fn(q, k, v, table, pos, ks, vs):
+            return ops.paged_attention(q, k, v, table, pos, k_scale=ks,
+                                       v_scale=vs, backend="mosaic")
+
+        text = _compile(fn, chip, ((BATCH, 1, NQ, H), jnp.bfloat16), *pool,
+                        ((BATCH, MAX_BLOCKS), jnp.int32),
+                        ((BATCH,), jnp.int32), *scales)
+    else:
+        def fn(q, kn, vn, k, v, blocks, start, length, ks, vs):
+            return ops.paged_prefill(q, kn, vn, k, v, blocks, start, length,
+                                     k_scale=ks, v_scale=vs,
+                                     backend="mosaic")
+
+        new = ((1, CHUNK, NKV, H), jnp.bfloat16)
+        text = _compile(fn, chip, ((1, CHUNK, NQ, H), jnp.bfloat16), new,
+                        new, *pool, ((MAX_BLOCKS,), jnp.int32),
+                        ((), jnp.int32), ((), jnp.int32), *scales)
+    op = re.search(r"%(\S+) = .*tpu_custom_call", text)
+    names = re.search(r"^NAMES = \((.*)\)$", (ROOFLINE / f"{kernel}.py")
+                      .read_text(), re.M).group(1)
+    assert op and op.group(1).startswith(kernel)
+    for name in re.findall(r'"([^"]+)"', names):
+        assert name in op.group(1)
