@@ -42,7 +42,7 @@ def _pool_case(rng, ctx, *, B, n_kv, group, H, bs, quantized):
     """A fully-occupied paged pool: every row holds ctx live tokens."""
     import jax.numpy as jnp
 
-    from repro.models.kv_cache import quantize_kv
+    from repro.models.kv_cache import merge_heads, quantize_kv, scale_rows
 
     maxb = ctx // bs
     nb = B * maxb + 1  # + trash block 0
@@ -51,9 +51,12 @@ def _pool_case(rng, ctx, *, B, n_kv, group, H, bs, quantized):
     if quantized:
         pool_k, k_scale = quantize_kv(kf)
         pool_v, v_scale = quantize_kv(vf)
+        # The pool's one-layer stack: scale rows, heads side by side.
+        k_scale, v_scale = scale_rows(k_scale)[None], scale_rows(v_scale)[None]
     else:
         pool_k, pool_v = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
         k_scale = v_scale = None
+    pool_k, pool_v = merge_heads(pool_k)[None], merge_heads(pool_v)[None]
     table = jnp.arange(1, B * maxb + 1, dtype=jnp.int32).reshape(B, maxb)
     q = jnp.asarray(rng.normal(size=(B, 1, n_kv * group, H)), jnp.bfloat16)
     q_pos = jnp.full((B,), ctx - 1, jnp.int32)
@@ -92,13 +95,14 @@ def run(quick: bool = False) -> dict:
     def gather_fn(max_blocks):
         def f(q, pk, pv, tbl, pos, ks, vs):
             k_r, v_r, kpos, ks_r, vs_r = paged_gather(
-                pk, pv, tbl, ks, vs, max_blocks=max_blocks)
+                pk, pv, tbl, ks, vs, max_blocks=max_blocks, layer=0,
+                n_kv=n_kv)
             return decode_attention(q, k_r, v_r, kpos, pos,
                                     k_scale=ks_r, v_scale=vs_r)
         return jax.jit(f, static_argnums=())
 
     fused_fn = jax.jit(lambda q, pk, pv, tbl, pos, ks, vs:
-                       ops.paged_attention(q, pk, pv, tbl, pos,
+                       ops.paged_attention(q, pk, pv, tbl, pos, layer=0,
                                            k_scale=ks, v_scale=vs,
                                            backend="interpret"))
 

@@ -229,25 +229,32 @@ def flash_attention(
 
 def paged_attention(
     q: jax.Array,            # (B, 1, NQ, H) — one new token per row
-    pool_k: jax.Array,       # (num_blocks, block_size, NKV, H)
+    pool_k: jax.Array,       # (L, num_blocks, block_size, NKV*H)
     pool_v: jax.Array,
     block_table: jax.Array,  # (B, max_blocks) int32, -1 = unallocated
     q_pos: jax.Array,        # (B,) per-row decode position
     *,
-    k_scale: Optional[jax.Array] = None,  # (num_blocks, block_size, NKV, 1)
+    layer: jax.Array,        # () int32 — the pool layer attended
+    k_scale: Optional[jax.Array] = None,  # (L, num_blocks, 1, NKV*bs)
     v_scale: Optional[jax.Array] = None,
     softcap: float = 0.0,
     blocks: Optional[Tuple[int, int, int]] = None,
     backend=None,
 ) -> jax.Array:
-    """Fused flash-decode attention over the paged KV pool.
+    """Fused flash-decode attention over layer ``layer`` of the stacked
+    paged KV pool.
 
-    Block-table resolution happens *inside* the kernel (scalar prefetch):
-    each grid step streams one live pool block into VMEM and folds it
-    into the online softmax — no contiguous gather of the pool is ever
-    materialized, per-row HBM traffic is the row's live blocks, and an
-    int8 pool (``k_scale``/``v_scale`` planes) dequantizes in-kernel.
-    The reference backend runs the gather-then-attend oracle
+    The pool planes are the whole ``(L, num_blocks, block_size, NKV·H)``
+    stack of ``kv_cache.PagedKVCache`` (an int8 pool's scale planes are
+    its lane-dense ``(L, num_blocks, 1, NKV·block_size)`` rows): the
+    kernel reads layer ``layer`` in place and in the stored layout, so a
+    scan over layers can carry the pool instead of slicing it.
+    Block-table resolution happens *inside* the kernel (scalar
+    prefetch): each grid step streams one live pool block into VMEM and
+    folds it into the online softmax — no contiguous gather of the pool
+    is ever materialized, per-row HBM traffic is the row's live blocks,
+    and an int8 pool (``k_scale``/``v_scale`` rows) dequantizes
+    in-kernel. The reference backend runs the gather-then-attend oracle
     (:func:`repro.kernels.ref.paged_attention_ref`), which is the
     bit-exactness specification the kernel is tested against.
 
@@ -259,17 +266,16 @@ def paged_attention(
     be = get_registry().resolve(backend)
     if be.is_reference:
         return _ref.paged_attention_ref(
-            q, pool_k, pool_v, block_table, q_pos,
+            q, pool_k, pool_v, block_table, q_pos, layer,
             k_scale=k_scale, v_scale=v_scale, softcap=softcap,
         )
-    bs, n_kv = pool_k.shape[1], pool_k.shape[2]
-    bh, _, _ = blocks or get_registry().paged_attention_plan(
-        n_kv, bs, pool_k.shape[3], be
-    )
+    H = q.shape[3]
+    bs, n_kv = pool_k.shape[2], pool_k.shape[3] // H
+    bh, _, _ = blocks or get_registry().paged_attention_plan(n_kv, bs, H, be)
     if bh <= 0 or n_kv % bh:
         bh = n_kv  # plans must divide the KV heads; fall back to all
     return _paged.paged_attention(
-        q, pool_k, pool_v, block_table, q_pos, k_scale, v_scale,
+        q, pool_k, pool_v, block_table, q_pos, layer, k_scale, v_scale,
         softcap=softcap, bh=bh, interpret=be.interpret,
     )
 
@@ -278,13 +284,14 @@ def paged_prefill(
     q: jax.Array,            # (1, Lc, NQ, H) — one row's chunk queries
     k_new: jax.Array,        # (1, Lc, NKV, H) — chunk K/V (unquantized)
     v_new: jax.Array,
-    pool_k: jax.Array,       # (num_blocks, block_size, NKV, H)
+    pool_k: jax.Array,       # (L, num_blocks, block_size, NKV*H)
     pool_v: jax.Array,
     blocks: jax.Array,       # (mb,) int32 row block table, -1 = unallocated
     start: jax.Array,        # () int32 chunk token 0's absolute position
     length: jax.Array,       # () int32 real chunk length (<= Lc)
     *,
-    k_scale: Optional[jax.Array] = None,  # (num_blocks, block_size, NKV, 1)
+    layer: jax.Array,        # () int32 — the pool layer attended and written
+    k_scale: Optional[jax.Array] = None,  # (L, num_blocks, 1, NKV*bs)
     v_scale: Optional[jax.Array] = None,
     softcap: float = 0.0,
     blocks_plan: Optional[Tuple[int, int, int]] = None,
@@ -292,35 +299,37 @@ def paged_prefill(
 ):
     """Fused paged chunked-prefill: attend a prompt chunk against
     [pool-resident prefix ++ chunk] causally AND write the chunk's K/V
-    into its destination pool blocks, in one kernel.
+    into its destination pool blocks, in one kernel, on layer ``layer``
+    of the stacked pool (planes laid out as in :func:`paged_attention`).
 
     The decode kernel's scalar-prefetch/block-table trick applied to the
     prefill grid: resident prefix blocks stream through the table index
     map (no per-layer HBM gather of the prefix), and destination blocks
     are written back through input/output-aliased pool refs from the
-    kernel epilogue (no post-prefill scatter round trip). int8 pools
-    quantize on write in-kernel with the exact `quantize_kv` math, so the
-    pool bytes are bit-identical to the scatter path's.
+    kernel epilogue (no post-prefill scatter round trip, and no copy of
+    any other block or layer). int8 pools quantize on write with the
+    exact `quantize_kv` math, so the pool bytes are bit-identical to the
+    scatter path's.
 
     Returns (attn (1, Lc, NQ, H) in q's dtype, pool_k, pool_v, k_scale,
-    v_scale) — scales are None passthroughs for a bf16 pool. The
-    reference backend runs the scatter-then-gather-attend oracle
-    (:func:`repro.kernels.ref.paged_prefill_ref`), the semantic spec the
-    kernel is tested against."""
+    v_scale) — the stacked planes; scales are None passthroughs for a
+    bf16 pool. The reference backend runs the scatter-then-gather-attend
+    oracle (:func:`repro.kernels.ref.paged_prefill_ref`), the semantic
+    spec the kernel is tested against."""
     be = get_registry().resolve(backend)
     if be.is_reference:
         return _ref.paged_prefill_ref(
-            q, k_new, v_new, pool_k, pool_v, blocks, start, length,
+            q, k_new, v_new, pool_k, pool_v, blocks, start, length, layer,
             k_scale=k_scale, v_scale=v_scale, softcap=softcap,
         )
-    bs, n_kv = pool_k.shape[1], pool_k.shape[2]
-    bh, _, _ = blocks_plan or get_registry().paged_prefill_plan(
-        n_kv, bs, pool_k.shape[3], be
-    )
+    H = q.shape[3]
+    bs, n_kv = pool_k.shape[2], pool_k.shape[3] // H
+    bh, _, _ = blocks_plan or get_registry().paged_prefill_plan(n_kv, bs, H,
+                                                                be)
     if bh <= 0 or n_kv % bh:
         bh = n_kv  # plans must divide the KV heads; fall back to all
     return _paged_pf.paged_prefill_attention(
-        q, k_new, v_new, pool_k, pool_v, blocks, start, length,
+        q, k_new, v_new, pool_k, pool_v, blocks, start, length, layer,
         k_scale, v_scale, softcap=softcap, bh=bh, interpret=be.interpret,
     )
 

@@ -11,11 +11,19 @@ attending), and no per-layer HBM gather of the resident prefix
 the prefix every layer; here prefix blocks stream through the block-table
 index map exactly like the decode kernel's).
 
+The pool is the stacked ``(L, num_blocks, block_size, NKV·H)`` planes
+of ``kv_cache.PagedKVCache`` (plus, for an int8 pool, its lane-dense
+``(L, num_blocks, 1, NKV·block_size)`` scale rows); the kernel reads and
+writes layer ``layer`` of them in place, in the layout they are stored
+in, so the model's scan over layers carries the whole pool and never
+slices a layer's plane out of it or lays one out again.
+
 Mechanics:
-  * The row's block table and ``(start, length)`` arrive via **scalar
-    prefetch** (``pltpu.PrefetchScalarGridSpec``) so the pool BlockSpec
-    index maps can resolve virtual block ``j`` to pool block ``table[j]``
-    before the grid step runs — one DMA streams exactly that block.
+  * The row's block table and ``(start, length, layer)`` arrive via
+    **scalar prefetch** (``pltpu.PrefetchScalarGridSpec``) so the pool
+    BlockSpec index maps can resolve virtual block ``j`` to block
+    ``(layer, table[j])`` of the stacked pool before the grid step runs —
+    one DMA streams exactly that block.
   * Grid is ``(NKV/bh, max_blocks)`` with the block dimension innermost;
     the online-softmax running max / denominator / accumulator for all
     ``Lc`` chunk queries live in VMEM scratch across a row's blocks.
@@ -29,11 +37,14 @@ Mechanics:
     input/output-aliased pool ref — the epilogue write. Non-destination
     steps remap the output to the trash block, so resident prefix blocks
     (possibly shared with other rows) are never rewritten.
-  * int8 pools quantize on write in-kernel (``kv_cache.quantize_kv``'s
-    exact per-(token, head) math, so pool bytes are bit-identical to the
-    scatter path's) and dequantize in-kernel on read: scores are computed
-    on int8 codes and rescaled per key slot, probabilities per value slot
-    — ``decode_attention``'s quantized math, like the decode kernel.
+  * int8 pools quantize on write with ``kv_cache.quantize_kv`` itself,
+    applied to the chunk before the kernel (so pool bytes are
+    bit-identical to the scatter path's): the kernel merges the chunk's
+    codes and, per destination block, its scale row (laid out like the
+    pool's rows) into the streamed tiles, and dequantizes in-kernel on
+    read: scores are computed on int8 codes and rescaled per key slot,
+    probabilities per value slot — ``decode_attention``'s quantized math,
+    like the decode kernel.
 
 Masking: key slot at absolute position ``kpos`` is visible to chunk query
 ``i`` iff its block is allocated and ``kpos <= start + i`` (causal);
@@ -58,27 +69,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import compiler_params as _compiler_params
-
-
-def _quantize_tile(x):
-    """In-kernel `kv_cache.quantize_kv`: per-(slot, head) int8 symmetric
-    codes + fp32 scales for a (bs, bh, H) tile. Must stay bit-identical
-    to the jnp helper — pool bytes written here are shared with readers
-    that assume the scatter path's exact quantization."""
-    xf = x.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-    scale = absmax / 127.0
-    inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
-    codes = jnp.clip(jnp.round(xf * inv), -128, 127).astype(jnp.int8)
-    return codes, scale
+from repro.kernels.common import head_tiles, slot_scales
 
 
 def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                   *rest, bs: int, n_blk: int, lc: int, g: int, scale: float,
                   softcap: float, quantized: bool):
     if quantized:
-        (ks_ref, vs_ref, o_ref, pk_out, pv_out, ks_out, vs_out,
-         m_ref, l_ref, acc_ref) = rest
+        (ks_ref, vs_ref, ksn_ref, vsn_ref, o_ref, pk_out, pv_out, ks_out,
+         vs_out, m_ref, l_ref, acc_ref) = rest
     else:
         o_ref, pk_out, pv_out, m_ref, l_ref, acc_ref = rest
     j = pl.program_id(1)
@@ -98,43 +97,35 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _step():
-        # Chunk rows [j*bs - start, +bs) as one contiguous window of the
-        # chunk K/V, which the wrapper pads by bs rows on each side so the
-        # window never leaves the buffer. Rows outside the chunk are
-        # replaced by pool values below, so the clamp changes nothing.
-        off = jnp.clip(j * bs - start, -bs, lc) + bs
-        kn = kn_ref[pl.ds(off, bs)]                   # (bs, bh, H)
-        vn = vn_ref[pl.ds(off, bs)]
-
-        def in_chunk(shape):
-            cidx = j * bs - start + jax.lax.broadcasted_iota(
-                jnp.int32, shape, 0)
+        # kn/vn hold the chunk's K/V (in the pool's dtype) laid out as
+        # this block's slots; slots outside the chunk keep their pool
+        # values.
+        def in_chunk(shape, dim, slot):
+            cidx = j * bs - start + slot(
+                jax.lax.broadcasted_iota(jnp.int32, shape, dim))
             return jnp.logical_and(cidx >= 0, cidx < length)
 
-        sel = in_chunk(kn.shape)
+        sel = in_chunk(k_ref.shape, 0, lambda t: t)
+        mk = jnp.where(sel, kn_ref[...], k_ref[...])  # (bs, bh*H)
+        mv = jnp.where(sel, vn_ref[...], v_ref[...])
         if quantized:
-            kq, ksc = _quantize_tile(kn)
-            vq, vsc = _quantize_tile(vn)
-            sel1 = in_chunk(ksc.shape)
-            mk = jnp.where(sel, kq, k_ref[0])
-            mv = jnp.where(sel, vq, v_ref[0])
-            msk = jnp.where(sel1, ksc, ks_ref[0])     # (bs, bh, 1) fp32
-            msv = jnp.where(sel1, vsc, vs_ref[0])
-        else:
-            mk = jnp.where(sel, kn.astype(k_ref.dtype), k_ref[0])
-            mv = jnp.where(sel, vn.astype(v_ref.dtype), v_ref[0])
+            # Scale rows: lane h*bs + t holds slot t of head h.
+            sel1 = in_chunk(ks_ref.shape, 1, lambda lane: lane % bs)
+            msk = jnp.where(sel1, ksn_ref[...], ks_ref[...])  # (1, bh*bs)
+            msv = jnp.where(sel1, vsn_ref[...], vs_ref[...])
 
         q = q_ref[...].astype(jnp.float32)            # (bh, Lc*G, H)
-        # (bh, Lc*G, H) x (bh, H, bs) -> (bh, Lc*G, bs), batched over bh.
+        bh = q.shape[0]
+        # (bh, Lc*G, H) x (bh, bs, H) -> (bh, Lc*G, bs), batched over bh.
         s = jax.lax.dot_general(
-            q, mk.astype(jnp.float32).transpose(1, 2, 0),
-            (((2,), (1,)), ((0,), (0,))),
+            q, head_tiles(mk.astype(jnp.float32), bh),
+            (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         if quantized:
             # Per-key-slot dequant of int8 codes (scores on codes, then
             # rescale — decode_attention's order).
-            s = s * msk[..., 0].transpose(1, 0)[:, None, :]
+            s = s * slot_scales(msk, bs)[:, None, :]
         s = s * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
@@ -155,9 +146,9 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2)
         if quantized:
             # Per-value-slot dequant folded into the probabilities.
-            p = p * msv[..., 0].transpose(1, 0)[:, None, :]
+            p = p * slot_scales(msv, bs)[:, None, :]
         pv = jax.lax.dot_general(
-            p, mv.astype(jnp.float32).transpose(1, 0, 2),
+            p, head_tiles(mv.astype(jnp.float32), bh),
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
@@ -169,11 +160,11 @@ def _chunk_kernel(tbl_ref, meta_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
         # output to the trash block, so this store is simply skipped.
         @pl.when(j >= start // bs)
         def _write():
-            pk_out[0] = mk
-            pv_out[0] = mv
+            pk_out[...] = mk
+            pv_out[...] = mv
             if quantized:
-                ks_out[0] = msk
-                vs_out[0] = msv
+                ks_out[...] = msk
+                vs_out[...] = msv
 
     @pl.when(j == n_blk - 1)
     def _finish():
@@ -187,12 +178,13 @@ def paged_prefill_attention(
     q: jax.Array,            # (1, Lc, NQ, H) — rope'd chunk queries
     k_new: jax.Array,        # (1, Lc, NKV, H) — chunk K/V (unquantized)
     v_new: jax.Array,
-    pool_k: jax.Array,       # (num_blocks, block_size, NKV, H)
+    pool_k: jax.Array,       # (L, num_blocks, block_size, NKV*H)
     pool_v: jax.Array,
     blocks: jax.Array,       # (mb,) int32 row block table, -1 = unallocated
     start: jax.Array,        # () int32 absolute position of chunk token 0
     length: jax.Array,       # () int32 real chunk length (<= Lc)
-    k_scale: jax.Array | None = None,  # (num_blocks, block_size, NKV, 1)
+    layer: jax.Array,        # () int32 — the pool layer attended and written
+    k_scale: jax.Array | None = None,  # (L, num_blocks, 1, NKV*block_size)
     v_scale: jax.Array | None = None,
     *,
     softcap: float = 0.0,
@@ -200,10 +192,13 @@ def paged_prefill_attention(
     interpret: bool = True,
 ):
     """Returns (attn (1, Lc, NQ, H) dtype of q, pool_k, pool_v, k_scale,
-    v_scale) — the pool planes updated in place (aliased) with the chunk's
-    K/V at positions [start, start + length)."""
+    v_scale) — the stacked pool planes updated in place (aliased) with
+    the chunk's K/V at positions [start, start + length) of layer
+    `layer`; every other layer and block keeps its bytes."""
+    from repro.models.kv_cache import merge_heads, quantize_kv, scale_rows
+
     _, Lc, NQ, H = q.shape
-    bs, NKV = pool_k.shape[1], pool_k.shape[2]
+    bs, NKV = pool_k.shape[2], pool_k.shape[3] // H
     G = NQ // NKV
     mb = blocks.shape[0]
     if bh <= 0 or NKV % bh:
@@ -211,39 +206,68 @@ def paged_prefill_attention(
     quantized = k_scale is not None
     # (NKV, Lc*G, H): a KV head's G query heads of every chunk query.
     qr = q.reshape(Lc, NKV, G, H).transpose(1, 0, 2, 3).reshape(NKV, Lc * G, H)
-    pad = ((bs, bs), (0, 0), (0, 0))
-    kn = jnp.pad(k_new.reshape(Lc, NKV, H), pad)     # (Lc + 2*bs, NKV, H)
-    vn = jnp.pad(v_new.reshape(Lc, NKV, H), pad)
+    # The chunk's K/V and scales as the slots of each block it writes
+    # (at most n_dst, from block start // bs on), so a destination step
+    # reads its tile whole; slots outside the chunk hold a clamped
+    # neighbour, which the kernel's merge never selects.
+    n_dst = (Lc + bs - 1) // bs + 1
+    slot = (jnp.asarray(start, jnp.int32) // bs * bs
+            + jnp.arange(n_dst * bs, dtype=jnp.int32).reshape(n_dst, bs))
+    src = jnp.clip(slot - jnp.asarray(start, jnp.int32), 0, Lc - 1)
+    kn, vn = k_new[0], v_new[0]                      # (Lc, NKV, H)
+    if quantized:
+        kn, ksn = quantize_kv(kn)
+        vn, vsn = quantize_kv(vn)
+        ksn, vsn = scale_rows(ksn[src]), scale_rows(vsn[src])
+    kn = merge_heads(kn).astype(pool_k.dtype)[src]  # (n_dst, bs, NKV*H)
+    vn = merge_heads(vn).astype(pool_v.dtype)[src]
     blocks = blocks.astype(jnp.int32)
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
-                      jnp.asarray(length, jnp.int32)])
+                      jnp.asarray(length, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
 
     def q_map(h, j, tbl, mt):
         return (h, 0, 0)
 
-    def new_map(h, j, tbl, mt):
-        return (0, h, 0)
+    def live(j, tbl, mt):
+        return jnp.logical_and(tbl[j] >= 0, j * bs <= mt[0] + mt[1] - 1)
 
-    def blk_map(h, j, tbl, mt):
+    def dst(j, tbl, mt):
+        return jnp.logical_and(live(j, tbl, mt), j >= mt[0] // bs)
+
+    def src_block(j, tbl, mt):
         # Dead steps (unallocated block / past the chunk) remap to the
         # trash block 0: a repeated index issues no new DMA.
-        live = jnp.logical_and(tbl[j] >= 0, j * bs <= mt[0] + mt[1] - 1)
-        return (jnp.where(live, jnp.maximum(tbl[j], 0), 0), 0, h, 0)
+        return jnp.where(live(j, tbl, mt), jnp.maximum(tbl[j], 0), 0)
 
-    def dst_map(h, j, tbl, mt):
+    def dst_block(j, tbl, mt):
         # Destination steps write back through the aliased pool ref; all
         # other steps dump the (unwritten) output tile into the trash
         # block so resident prefix blocks are never rewritten.
-        live = jnp.logical_and(tbl[j] >= 0, j * bs <= mt[0] + mt[1] - 1)
-        dst = jnp.logical_and(live, j >= mt[0] // bs)
-        return (jnp.where(dst, jnp.maximum(tbl[j], 0), 0), 0, h, 0)
+        return jnp.where(dst(j, tbl, mt), jnp.maximum(tbl[j], 0), 0)
 
+    def blk_map(h, j, tbl, mt):
+        return (mt[2], src_block(j, tbl, mt), 0, h)
+
+    def dst_map(h, j, tbl, mt):
+        return (mt[2], dst_block(j, tbl, mt), 0, h)
+
+    def new_index(j, tbl, mt):
+        # Only destination steps merge the chunk; others re-read tile 0.
+        return jnp.where(dst(j, tbl, mt), j - mt[0] // bs, 0)
+
+    def new_map(h, j, tbl, mt):
+        return (new_index(j, tbl, mt), 0, h)
+
+    pool_spec = pl.BlockSpec((None, None, bs, bh * H), blk_map)
+    pool_out_spec = pl.BlockSpec((None, None, bs, bh * H), dst_map)
+    new_spec = pl.BlockSpec((None, bs, bh * H), new_map)
     in_specs = [
         pl.BlockSpec((bh, Lc * G, H), q_map),
-        pl.BlockSpec((Lc + 2 * bs, bh, H), new_map),
-        pl.BlockSpec((Lc + 2 * bs, bh, H), new_map),
-        pl.BlockSpec((1, bs, bh, H), blk_map),
-        pl.BlockSpec((1, bs, bh, H), blk_map),
+        new_spec,
+        new_spec,
+        pool_spec,
+        pool_spec,
     ]
     operands = [qr, kn, vn, pool_k, pool_v]
     out_shapes = [
@@ -253,25 +277,22 @@ def paged_prefill_attention(
     ]
     out_specs = [
         pl.BlockSpec((bh, Lc * G, H), q_map),
-        pl.BlockSpec((1, bs, bh, H), dst_map),
-        pl.BlockSpec((1, bs, bh, H), dst_map),
+        pool_out_spec,
+        pool_out_spec,
     ]
     # Operand indices count the scalar-prefetch args (blocks=0, meta=1).
     aliases = {5: 1, 6: 2}
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, bh, 1), blk_map),
-            pl.BlockSpec((1, bs, bh, 1), blk_map),
-        ]
-        operands += [k_scale, v_scale]
+        row_spec = pl.BlockSpec((None, None, 1, bh * bs), blk_map)
+        new_row_spec = pl.BlockSpec((None, 1, bh * bs), new_map)
+        in_specs += [row_spec, row_spec, new_row_spec, new_row_spec]
+        operands += [k_scale, v_scale, ksn, vsn]
         out_shapes += [
             jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
             jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
         ]
-        out_specs += [
-            pl.BlockSpec((1, bs, bh, 1), dst_map),
-            pl.BlockSpec((1, bs, bh, 1), dst_map),
-        ]
+        row_out_spec = pl.BlockSpec((None, None, 1, bh * bs), dst_map)
+        out_specs += [row_out_spec, row_out_spec]
         aliases.update({7: 3, 8: 4})
 
     kernel = functools.partial(

@@ -114,25 +114,34 @@ def wkv6_ref(
 
 def paged_attention_ref(
     q: jax.Array,            # (B, 1, NQ, H)
-    pool_k: jax.Array,       # (num_blocks, block_size, NKV, H)
+    pool_k: jax.Array,       # (L, num_blocks, block_size, NKV*H)
     pool_v: jax.Array,
     block_table: jax.Array,  # (B, max_blocks) int32, -1 = unallocated
     q_pos: jax.Array,        # (B,) per-row decode position
-    k_scale: jax.Array | None = None,  # (num_blocks, block_size, NKV, 1)
+    layer: jax.Array,        # () int32 — the pool layer attended
+    k_scale: jax.Array | None = None,  # (L, num_blocks, 1, NKV*block_size)
     v_scale: jax.Array | None = None,
     softcap: float = 0.0,
 ) -> jax.Array:
     """Gather-then-attend oracle for the fused paged-attention kernel.
 
-    Materializes each row's blocks in table order (the contiguous
-    slot == position layout) and runs the same one-token masked-softmax
-    math as ``models.common.decode_attention`` — including the int8-pool
-    per-slot rescaling. This IS the "separate buffer" the fused kernel
-    eliminates; it survives as the semantic specification."""
+    Slices layer `layer` out of the stacked pool, materializes each
+    row's blocks in table order (the contiguous slot == position layout)
+    and runs the same one-token masked-softmax math as
+    ``models.common.decode_attention`` — including the int8-pool per-slot
+    rescaling. This IS the "separate buffer" the fused kernel eliminates;
+    it survives as the semantic specification."""
+    from repro.models.kv_cache import scale_slots, split_heads
+
     B, _, NQ, H = q.shape
-    bs, NKV = pool_k.shape[1], pool_k.shape[2]
+    bs, NKV = pool_k.shape[2], pool_k.shape[3] // H
     G = NQ // NKV
     max_blocks = block_table.shape[1]
+    pool_k = split_heads(pool_k[layer], NKV)
+    pool_v = split_heads(pool_v[layer], NKV)
+    if k_scale is not None:
+        k_scale = scale_slots(k_scale[layer], bs)
+        v_scale = scale_slots(v_scale[layer], bs)
     tbl = jnp.maximum(block_table, 0)
     k_rows = pool_k[tbl].reshape(B, max_blocks * bs, NKV, H)
     v_rows = pool_v[tbl].reshape(B, max_blocks * bs, NKV, H)
@@ -164,36 +173,38 @@ def paged_prefill_ref(
     q: jax.Array,            # (1, Lc, NQ, H)
     k_new: jax.Array,        # (1, Lc, NKV, H) — chunk K/V, unquantized
     v_new: jax.Array,
-    pool_k: jax.Array,       # (num_blocks, block_size, NKV, H)
+    pool_k: jax.Array,       # (L, num_blocks, block_size, NKV*H)
     pool_v: jax.Array,
     blocks: jax.Array,       # (mb,) int32 row block table, -1 = unallocated
     start: jax.Array,        # () int32 chunk token 0's absolute position
     length: jax.Array,       # () int32 real chunk length (<= Lc)
-    k_scale: jax.Array | None = None,
+    layer: jax.Array,        # () int32 — the pool layer attended and written
+    k_scale: jax.Array | None = None,  # (L, num_blocks, 1, NKV*block_size)
     v_scale: jax.Array | None = None,
     softcap: float = 0.0,
 ):
     """Scatter-then-gather-attend oracle for the chunked-prefill kernel.
 
-    Writes the chunk into the pool with `paged_chunk_write` (the exact
-    quantize-on-write math the kernel inlines), then gathers the row's
-    blocks in table order and runs a full fp32 masked softmax — chunk
-    query i sees allocated positions <= start + i, padded queries
-    (i >= length) see nothing and output zeros. Attending *through the
-    pool* is the point: an int8 pool's chunk keys come back as
-    dequantize(quantize(k)), the `_kv_attn_view` contract."""
+    Writes the chunk into layer `layer` of the stacked pool with
+    `paged_chunk_write` (the exact quantize-on-write math the kernel's
+    wrapper applies), then gathers the row's blocks of that layer in
+    table order and runs a full fp32 masked softmax — chunk query i sees
+    allocated positions <= start + i, padded queries (i >= length) see
+    nothing and output zeros. Attending *through the pool* is the point:
+    an int8 pool's chunk keys come back as dequantize(quantize(k)), the
+    `_kv_attn_view` contract. Returns the stacked planes."""
     from repro.models import kv_cache as _kvc
 
     _, Lc, NQ, H = q.shape
-    bs, NKV = pool_k.shape[1], pool_k.shape[2]
+    bs, NKV = pool_k.shape[2], pool_k.shape[3] // H
     G = NQ // NKV
     mb = blocks.shape[0]
     pool_k, pool_v, k_scale, v_scale = _kvc.paged_chunk_write(
         pool_k, pool_v, blocks, k_new, v_new, start, length, bs,
-        k_scale, v_scale)
+        k_scale, v_scale, layer=layer)
     tbl = jnp.maximum(blocks, 0)
-    k_rows = pool_k[tbl].reshape(mb * bs, NKV, H)
-    v_rows = pool_v[tbl].reshape(mb * bs, NKV, H)
+    k_rows = pool_k[layer, tbl].reshape(mb * bs, NKV, H)
+    v_rows = pool_v[layer, tbl].reshape(mb * bs, NKV, H)
     virt = jnp.arange(mb * bs, dtype=jnp.int32)
     alloc = jnp.repeat(blocks >= 0, bs)
     kpos = jnp.where(alloc, virt, -1)
@@ -202,7 +213,7 @@ def paged_prefill_ref(
     s = jnp.einsum("qngh,snh->nqgs", qr.astype(jnp.float32),
                    k_rows.astype(jnp.float32))
     if k_scale is not None:
-        ks = k_scale[tbl].reshape(mb * bs, NKV)
+        ks = _kvc.scale_slots(k_scale[layer, tbl], bs).reshape(mb * bs, NKV)
         s = s * ks.T[:, None, None, :]
     s = s * (H**-0.5)
     if softcap:
@@ -214,7 +225,7 @@ def paged_prefill_ref(
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(valid[None, :, None, :], p, 0.0)
     if v_scale is not None:
-        vs = v_scale[tbl].reshape(mb * bs, NKV)
+        vs = _kvc.scale_slots(v_scale[layer, tbl], bs).reshape(mb * bs, NKV)
         p = p * vs.T[:, None, None, :]
     out = jnp.einsum("nqgs,snh->qngh", p, v_rows.astype(jnp.float32))
     attn = out.reshape(1, Lc, NQ, H).astype(q.dtype)

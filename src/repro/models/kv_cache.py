@@ -186,10 +186,11 @@ class PagedKVCache:
     of the paper's utilization argument: capacity is sized for the *actual*
     resident tokens, not a worst-case per-slot reservation).
 
-    k/v: (L, num_blocks, block_size, NKV, H) — one pool shared by every
-    batch slot. block_table: (B, max_blocks) int32 maps a row's virtual
-    block j (covering absolute positions [j·bs, (j+1)·bs)) to a pool block;
-    -1 = unallocated. Pool block 0 is a reserved trash block: writes from
+    k/v: (L, num_blocks, block_size, NKV·H) — one pool shared by every
+    batch slot, each slot's heads side by side in one row (the bytes of
+    an (…, NKV, H) array; `split_heads` gives that view). block_table:
+    (B, max_blocks) int32 maps a row's virtual block j (covering absolute
+    positions [j·bs, (j+1)·bs)) to a pool block; -1 = unallocated. Pool block 0 is a reserved trash block: writes from
     free slots and unallocated virtual blocks land there and are never
     read. length: (B,) tokens written per row.
 
@@ -200,10 +201,25 @@ class PagedKVCache:
     contiguous one.
 
     Optional int8 pool (the contiguous cache's kv_cache_quant applied to
-    the block pool): k/v hold int8 codes and k_scale/v_scale hold
-    per-(slot, head) fp32 scale planes (L, num_blocks, block_size, NKV, 1)
-    written by the quantizing `paged_cache_write` — roughly 2× the tokens
-    per pooled byte.
+    the block pool): k/v hold int8 codes and k_scale/v_scale hold the
+    per-(slot, head) fp32 scales, written by the quantizing
+    `paged_cache_write` — roughly 2× the tokens per pooled byte. A scale
+    plane is (L, num_blocks, 1, NKV·block_size): one lane-dense row per
+    (layer, block), head-major, so the scale of slot s, head h sits at
+    lane h·block_size + s (`scale_rows` / `scale_slots` convert to and
+    from the per-slot (…, block_size, NKV, 1) view).
+
+    Both layouts are what the TPU stores without padding and what Mosaic
+    reads as stored: an (…, NKV, H) code plane would be padded in the
+    tiled layout the kernels need (H = 160 to 256 lanes), so XLA keeps
+    it with num_blocks minor and relays it out around every kernel call;
+    an (…, NKV, 1) scale plane would be padded 128-fold. Rows of NKV·H
+    and NKV·block_size lanes (multiples of 128 at served widths) are not.
+
+    The decode and chunk programs carry the stacked planes through their
+    scan over layers and update them in place: every writer and both
+    pool kernels take the whole (L, …) plane plus a layer index, so no
+    per-layer slice of the pool is ever copied out or stacked back.
 
     Pool blocks have no intrinsic owner: nothing stops two rows' tables
     from mapping to the same pool block, which is exactly how the
@@ -223,11 +239,11 @@ class PagedKVCache:
     the moment any digest is registered against it (the scheduler
     enforces this by copy-on-write even for a sole referencer)."""
 
-    k: jax.Array
+    k: jax.Array                         # (L, num_blocks, bs, NKV*H)
     v: jax.Array
     block_table: jax.Array
     length: jax.Array
-    k_scale: Optional[jax.Array] = None  # (L, num_blocks, bs, NKV, 1) fp32
+    k_scale: Optional[jax.Array] = None  # (L, num_blocks, 1, NKV*bs) fp32
     v_scale: Optional[jax.Array] = None
     block_size: int = 16
 
@@ -257,18 +273,44 @@ class PagedKVCache:
              dtype=jnp.bfloat16, quantized: bool = False) -> "PagedKVCache":
         kd = jnp.int8 if quantized else dtype
         scale = (
-            jnp.zeros((layers, num_blocks, block_size, n_kv, 1), jnp.float32)
+            jnp.zeros((layers, num_blocks, 1, n_kv * block_size), jnp.float32)
             if quantized else None
         )
         return PagedKVCache(
-            k=jnp.zeros((layers, num_blocks, block_size, n_kv, head_dim), kd),
-            v=jnp.zeros((layers, num_blocks, block_size, n_kv, head_dim), kd),
+            k=jnp.zeros((layers, num_blocks, block_size, n_kv * head_dim), kd),
+            v=jnp.zeros((layers, num_blocks, block_size, n_kv * head_dim), kd),
             block_table=jnp.full((batch, max_blocks), -1, jnp.int32),
             length=jnp.zeros((batch,), jnp.int32),
             k_scale=scale,
             v_scale=jnp.copy(scale) if quantized else None,
             block_size=block_size,
         )
+
+
+def merge_heads(x):
+    """(..., NKV, H) -> the pool's (..., NKV·H) rows (a reshape)."""
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+
+
+def split_heads(rows, n_kv: int):
+    """Inverse of `merge_heads`: (..., NKV·H) -> (..., NKV, H)."""
+    return rows.reshape(*rows.shape[:-1], n_kv, rows.shape[-1] // n_kv)
+
+
+def scale_rows(slots):
+    """Per-slot scales (..., block_size, NKV, 1) -> the pool's scale rows
+    (..., 1, NKV·block_size), head-major: slot s of head h at lane
+    h·block_size + s. Exact (a transpose); works on numpy arrays too."""
+    x = slots[..., 0].swapaxes(-1, -2)
+    return x.reshape(*x.shape[:-2], 1, x.shape[-2] * x.shape[-1])
+
+
+def scale_slots(rows, block_size: int):
+    """Inverse of `scale_rows`: (..., 1, NKV·block_size) ->
+    (..., block_size, NKV, 1)."""
+    x = rows.reshape(*rows.shape[:-2], rows.shape[-1] // block_size,
+                     block_size)
+    return x.swapaxes(-1, -2)[..., None]
 
 
 def paged_slot(block_table, pos, block_size: int):
@@ -281,37 +323,65 @@ def paged_slot(block_table, pos, block_size: int):
     return jnp.maximum(blk, 0), pos % block_size
 
 
+def _write_scales(k_scale, v_scale, layer, blk, off, ks, vs,
+                  block_size: int):
+    """Write per-(token, head) scales (N, NKV) into the stacked scale rows
+    at (layer, blk[n], lane h·block_size + off[n]), one token's rows at a
+    time (read, replace its NKV lanes, write back). A scatter would do the
+    same, but on a TPU XLA runs it on a relaid-out copy of the whole
+    stacked plane; these row updates stay in place."""
+    lane = jnp.arange(k_scale.shape[-1], dtype=jnp.int32)
+    ks = jnp.repeat(ks, block_size, axis=1)       # (N, W): head h's lanes
+    vs = jnp.repeat(vs, block_size, axis=1)
+
+    def one(n, planes):
+        at = (layer, blk[n], 0, 0)
+        keep = lane % block_size != off[n]
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                p, jnp.where(keep, jax.lax.dynamic_slice(
+                    p, at, (1, 1, 1, p.shape[-1])), new[n]), at)
+            for p, new in zip(planes, (ks, vs)))
+
+    return jax.lax.fori_loop(0, blk.shape[0], one, (k_scale, v_scale))
+
+
 def paged_cache_write(pool_k, pool_v, block_table, k_new, v_new, pos,
-                      block_size: int, k_scale=None, v_scale=None):
-    """Write one token's k/v (B, 1, NKV, H) into a single layer's pool
-    (num_blocks, block_size, NKV, H) at per-row positions `pos` (B,).
-    Live rows own disjoint blocks; free rows all write the trash block.
+                      block_size: int, k_scale=None, v_scale=None, *, layer):
+    """Write one token's k/v (B, 1, NKV, H) into layer `layer` of the
+    stacked pool (L, num_blocks, block_size, NKV·H) at per-row positions
+    `pos` (B,): only the B written slots change, in place inside a jitted
+    program that donates or carries the planes. Live rows own disjoint
+    blocks; free rows all write the trash block.
 
     When the pool is int8 (scale planes passed), the incoming bf16 k/v is
     quantized here — per-(token, head) symmetric codes land in the pool
-    and their fp32 scales in the matching scale-plane slots. Returns
+    and their fp32 scales in the matching scale-row lanes. Returns
     (pool_k, pool_v, k_scale, v_scale); the scales are None passthroughs
     for an unquantized pool."""
     blk, off = paged_slot(block_table, pos, block_size)
     if k_scale is not None:
         k_new, ks = quantize_kv(k_new)
         v_new, vs = quantize_kv(v_new)
-        k_scale = k_scale.at[blk, off].set(ks[:, 0])
-        v_scale = v_scale.at[blk, off].set(vs[:, 0])
-    pool_k = pool_k.at[blk, off].set(k_new[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[blk, off].set(v_new[:, 0].astype(pool_v.dtype))
+        k_scale, v_scale = _write_scales(k_scale, v_scale, layer, blk, off,
+                                         ks[:, 0, :, 0], vs[:, 0, :, 0],
+                                         block_size)
+    pool_k = pool_k.at[layer, blk, off].set(
+        merge_heads(k_new[:, 0]).astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, blk, off].set(
+        merge_heads(v_new[:, 0]).astype(pool_v.dtype))
     return pool_k, pool_v, k_scale, v_scale
 
 
 def paged_chunk_write(pool_k, pool_v, blocks, k_new, v_new, start, length,
-                      block_size: int, k_scale=None, v_scale=None):
-    """Write one row's `length`-token chunk (1, Lc, NKV, H) into a single
-    layer's pool at absolute positions [start, start + length), routed
-    through the row's own block table `blocks` (mb,). Padded chunk slots
-    (t >= length) and positions past the table are routed to the trash
-    block 0, so a fixed-shape Lc never touches blocks a later chunk owns.
-    int8 pools quantize on write exactly like `paged_cache_write`.
-    Returns (pool_k, pool_v, k_scale, v_scale)."""
+                      block_size: int, k_scale=None, v_scale=None, *, layer):
+    """Write one row's `length`-token chunk (1, Lc, NKV, H) into layer
+    `layer` of the stacked pool at absolute positions [start, start +
+    length), routed through the row's own block table `blocks` (mb,).
+    Padded chunk slots (t >= length) and positions past the table are
+    routed to the trash block 0, so a fixed-shape Lc never touches blocks
+    a later chunk owns. int8 pools quantize on write exactly like
+    `paged_cache_write`. Returns (pool_k, pool_v, k_scale, v_scale)."""
     Lc = k_new.shape[1]
     pos = jnp.asarray(start, jnp.int32) + jnp.arange(Lc, dtype=jnp.int32)
     valid = jnp.arange(Lc) < length
@@ -322,23 +392,25 @@ def paged_chunk_write(pool_k, pool_v, blocks, k_new, v_new, start, length,
     if k_scale is not None:
         k_new, ks = quantize_kv(k_new)
         v_new, vs = quantize_kv(v_new)
-        k_scale = k_scale.at[blk, off].set(ks)
-        v_scale = v_scale.at[blk, off].set(vs)
-    pool_k = pool_k.at[blk, off].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[blk, off].set(v_new.astype(pool_v.dtype))
+        k_scale, v_scale = _write_scales(k_scale, v_scale, layer, blk, off,
+                                         ks[..., 0], vs[..., 0], block_size)
+    pool_k = pool_k.at[layer, blk, off].set(
+        merge_heads(k_new).astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, blk, off].set(
+        merge_heads(v_new).astype(pool_v.dtype))
     return pool_k, pool_v, k_scale, v_scale
 
 
 def paged_gather(pool_k, pool_v, block_table, k_scale=None, v_scale=None,
-                 max_blocks: Optional[int] = None):
-    """Gather each row's blocks in table order from a single layer's pool:
-    returns (k (B, S, NKV, H), v, kpos (B, S), k_scale, v_scale) with
-    S = max_blocks · block_size and kpos[b, p] = p where row b's virtual
-    block p // bs is allocated, -1 elsewhere — the exact
-    (values, positions) layout of the contiguous cache, ready for
-    decode_attention. Scale planes of an int8 pool gather the same way
-    ((B, S, NKV, 1) — decode_attention's quantized-cache layout) and come
-    back None for a bf16 pool.
+                 max_blocks: Optional[int] = None, *, layer, n_kv: int):
+    """Gather each row's blocks in table order from layer `layer` of the
+    stacked pool (`n_kv` KV heads a slot): returns (k (B, S, NKV, H), v,
+    kpos (B, S), k_scale, v_scale) with S = max_blocks · block_size and
+    kpos[b, p] = p where row b's virtual block p // bs is allocated, -1
+    elsewhere — the exact (values, positions) layout of the contiguous
+    cache, ready for decode_attention. Scale rows of an int8 pool gather
+    the same way and come back per slot ((B, S, NKV, 1) —
+    decode_attention's quantized-cache layout), None for a bf16 pool.
 
     `max_blocks` (host-known, static) clamps the gather to the first
     `max_blocks` table columns: when the caller knows no live row has
@@ -349,17 +421,21 @@ def paged_gather(pool_k, pool_v, block_table, k_scale=None, v_scale=None,
     if max_blocks is not None:
         block_table = block_table[:, :max_blocks]
     B, n_blocks = block_table.shape
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     tbl = jnp.maximum(block_table, 0)
-    k_rows = pool_k[tbl].reshape(B, n_blocks * bs, *pool_k.shape[2:])
-    v_rows = pool_v[tbl].reshape(B, n_blocks * bs, *pool_v.shape[2:])
+    k_rows = split_heads(pool_k[layer, tbl], n_kv).reshape(
+        B, n_blocks * bs, n_kv, -1)
+    v_rows = split_heads(pool_v[layer, tbl], n_kv).reshape(
+        B, n_blocks * bs, n_kv, -1)
     virt = jnp.arange(n_blocks * bs, dtype=jnp.int32)
     alloc = jnp.repeat(block_table >= 0, bs, axis=1)
     kpos = jnp.where(alloc, virt[None, :], -1)
     ks_rows = vs_rows = None
     if k_scale is not None:
-        ks_rows = k_scale[tbl].reshape(B, n_blocks * bs, *k_scale.shape[2:])
-        vs_rows = v_scale[tbl].reshape(B, n_blocks * bs, *v_scale.shape[2:])
+        ks_rows = scale_slots(k_scale[layer, tbl], bs).reshape(
+            B, n_blocks * bs, n_kv, 1)
+        vs_rows = scale_slots(v_scale[layer, tbl], bs).reshape(
+            B, n_blocks * bs, n_kv, 1)
     return k_rows, v_rows, kpos, ks_rows, vs_rows
 
 
@@ -521,17 +597,19 @@ def scatter_into_paged(batch: DecodeCache, solo: DecodeCache, slot,
     dst = jnp.maximum(
         jnp.take(row_blocks, jnp.arange(nb), mode="fill", fill_value=-1), 0
     )
-    k = kv.k.at[:, dst].set(as_blocks(solo.kv.k).astype(kv.k.dtype))
-    v = kv.v.at[:, dst].set(as_blocks(solo.kv.v).astype(kv.v.dtype))
+    k = kv.k.at[:, dst].set(
+        merge_heads(as_blocks(solo.kv.k)).astype(kv.k.dtype))
+    v = kv.v.at[:, dst].set(
+        merge_heads(as_blocks(solo.kv.v)).astype(kv.v.dtype))
     ks = vs = None
     if kv.quantized:
         # The solo prefill cache is quantized too (same cfg): its codes
         # scattered above, its per-(slot, head) scales go to the matching
-        # scale-plane blocks.
+        # scale rows.
         ks = kv.k_scale.at[:, dst].set(
-            as_blocks(solo.kv.k_scale).astype(kv.k_scale.dtype))
+            scale_rows(as_blocks(solo.kv.k_scale)).astype(kv.k_scale.dtype))
         vs = kv.v_scale.at[:, dst].set(
-            as_blocks(solo.kv.v_scale).astype(kv.v_scale.dtype))
+            scale_rows(as_blocks(solo.kv.v_scale)).astype(kv.v_scale.dtype))
     table = jax.lax.dynamic_update_slice(
         kv.block_table, row_blocks[None, : kv.blocks_per_row], (slot, 0)
     )
@@ -578,14 +656,16 @@ def scatter_suffix_into_paged(batch: DecodeCache, solo: DecodeCache, slot,
         jnp.take(row_blocks, start_block + jnp.arange(nb), mode="fill",
                  fill_value=-1), 0
     )
-    k = kv.k.at[:, dst].set(as_blocks(solo.kv.k).astype(kv.k.dtype))
-    v = kv.v.at[:, dst].set(as_blocks(solo.kv.v).astype(kv.v.dtype))
+    k = kv.k.at[:, dst].set(
+        merge_heads(as_blocks(solo.kv.k)).astype(kv.k.dtype))
+    v = kv.v.at[:, dst].set(
+        merge_heads(as_blocks(solo.kv.v)).astype(kv.v.dtype))
     ks = vs = None
     if kv.quantized:
         ks = kv.k_scale.at[:, dst].set(
-            as_blocks(solo.kv.k_scale).astype(kv.k_scale.dtype))
+            scale_rows(as_blocks(solo.kv.k_scale)).astype(kv.k_scale.dtype))
         vs = kv.v_scale.at[:, dst].set(
-            as_blocks(solo.kv.v_scale).astype(kv.v_scale.dtype))
+            scale_rows(as_blocks(solo.kv.v_scale)).astype(kv.v_scale.dtype))
     table = jax.lax.dynamic_update_slice(
         kv.block_table, row_blocks[None, : kv.blocks_per_row], (slot, 0)
     )
@@ -672,23 +752,24 @@ def write_pool_block(cache: DecodeCache, dst, k, v,
     """Write one block's worth of K/V bytes into pool block `dst` across
     every layer — the swap-in half of the host-RAM block tier. `k`/`v`
     are (L, block_size, NKV, H) arrays in the pool's dtype (int8 codes
-    for a quantized pool, with the fp32 `k_scale`/`v_scale` planes
-    (L, block_size, NKV, 1) alongside); they round-trip device → pinned
-    host numpy → device verbatim, which is what makes a warm-from-host
-    admission bitwise identical to the blocks' original residency.
+    for a quantized pool, with the fp32 `k_scale`/`v_scale` per-slot
+    scales (L, block_size, NKV, 1) alongside), laid into the pool's rows
+    by `merge_heads` and `scale_rows`; they round-trip device → pinned host numpy →
+    device verbatim, which is what makes a warm-from-host admission
+    bitwise identical to the blocks' original residency.
 
     `dst` may be a traced scalar — one compiled write serves every
     destination block."""
     kv: PagedKVCache = cache.kv
     dst = jnp.asarray(dst, jnp.int32)
-    kk = kv.k.at[:, dst].set(jnp.asarray(k, kv.k.dtype))
-    vv = kv.v.at[:, dst].set(jnp.asarray(v, kv.v.dtype))
+    kk = kv.k.at[:, dst].set(merge_heads(jnp.asarray(k, kv.k.dtype)))
+    vv = kv.v.at[:, dst].set(merge_heads(jnp.asarray(v, kv.v.dtype)))
     ks = vs = None
     if kv.quantized:
         ks = kv.k_scale.at[:, dst].set(
-            jnp.asarray(k_scale, kv.k_scale.dtype))
+            scale_rows(jnp.asarray(k_scale, kv.k_scale.dtype)))
         vs = kv.v_scale.at[:, dst].set(
-            jnp.asarray(v_scale, kv.v_scale.dtype))
+            scale_rows(jnp.asarray(v_scale, kv.v_scale.dtype)))
     return dataclasses.replace(cache, kv=dataclasses.replace(
         kv, k=kk, v=vv, k_scale=ks, v_scale=vs))
 

@@ -235,18 +235,21 @@ def block_decode_paged(
     cfg: ModelConfig,
     x: jax.Array,            # (B, 1, d)
     pos: jax.Array,          # (B,) per-slot positions
-    pool_k, pool_v,          # (num_blocks, block_size, NKV, H)
+    pool,                    # (k, v, k_scale, v_scale) stacked pool planes
     block_table,             # (B, max_blocks)
     block_size: int,
-    k_scale=None, v_scale=None,  # (num_blocks, bs, NKV, 1) int8-pool planes
+    layer,                   # () int32 — this block's pool layer
     fused: bool = True,
     gather_blocks: Optional[int] = None,
 ):
-    """Single-token block against one layer's slice of the paged pool:
-    scatter the new k/v into pos's (block, offset) — quantizing on the way
-    in when the pool is int8 — then attend through the fused
-    `ops.paged_attention` kernel, which resolves the block table inside
-    the kernel and never materializes a contiguous copy of the pool.
+    """Single-token block against layer `layer` of the stacked paged
+    pool: write the new k/v into pos's (block, offset) of that layer in
+    place — quantizing on the way in when the pool is int8 (scale planes
+    not None) — then attend through the fused `ops.paged_attention`
+    kernel, which reads that layer of the stacked planes as stored,
+    resolves the block table inside the kernel and never materializes a
+    contiguous copy of the pool. Returns (x, pool) with the updated
+    planes.
 
     `fused=False` keeps the original gather-then-attend composition
     (`paged_gather` → `decode_attention`, value/position layout identical
@@ -256,28 +259,40 @@ def block_decode_paged(
     h = cm.apply_norm(x, p["ln1"], cfg.norm)
     q, k, v = _attention_qkv(p, cfg, h, pos[:, None])
     pool_k, pool_v, k_scale, v_scale = paged_cache_write(
-        pool_k, pool_v, block_table, k, v, pos, block_size,
-        k_scale=k_scale, v_scale=v_scale,
+        *pool[:2], block_table, k, v, pos, block_size,
+        k_scale=pool[2], v_scale=pool[3], layer=layer,
     )
     with jax.named_scope("attention"):
         if fused:
             from repro.kernels import ops
 
             attn = ops.paged_attention(
-                q, pool_k, pool_v, block_table, pos,
+                q, pool_k, pool_v, block_table, pos, layer=layer,
                 k_scale=k_scale, v_scale=v_scale,
                 softcap=cfg.attn_logit_softcap,
             )
         else:
             k_rows, v_rows, kpos, ks_rows, vs_rows = paged_gather(
                 pool_k, pool_v, block_table, k_scale, v_scale,
-                max_blocks=gather_blocks,
+                max_blocks=gather_blocks, layer=layer, n_kv=cfg.n_kv_heads,
             )
             attn = cm.decode_attention(
                 q, k_rows, v_rows, kpos, pos, softcap=cfg.attn_logit_softcap,
                 k_scale=ks_rows, v_scale=vs_rows,
             )
-    return _block_post_attn(p, cfg, x, attn), pool_k, pool_v, k_scale, v_scale
+    return (_block_post_attn(p, cfg, x, attn),
+            (pool_k, pool_v, k_scale, v_scale))
+
+
+def _pool(kv: PagedKVCache):
+    """The stacked pool planes a layer scan carries: (k, v, k_scale,
+    v_scale), the scales None for a bf16 pool."""
+    return kv.k, kv.v, kv.k_scale, kv.v_scale
+
+
+def _layer_ids(cfg: ModelConfig) -> jax.Array:
+    """The layer index each scan step hands its pool kernels."""
+    return jnp.arange(cfg.num_layers, dtype=jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -475,11 +490,11 @@ def prefill_suffix(params, cfg: ModelConfig, batch):
       lengths (1,)          real suffix length
       start ()              absolute position of the first suffix token ==
                             number of prefix positions resident in the pool
-      pool_k / pool_v       (L, num_blocks, bs, NKV, H) pool planes
+      pool_k / pool_v       (L, num_blocks, bs, NKV·H) pool planes
       prefix_blocks (mb,)   the row's pool blocks covering positions
                             [0, start) in virtual-block order; -1 entries
                             gather the trash block and are masked out
-      pool_k_scale / pool_v_scale   int8-pool scale planes (quantized only)
+      pool_k_scale / pool_v_scale   int8-pool scale rows (quantized only)
 
     Returns ``(DecodeCache, logits)``; the solo cache holds ONLY the
     suffix: cache slot ``t`` ↔ absolute position ``start + t`` (see
@@ -498,14 +513,16 @@ def prefill_suffix(params, cfg: ModelConfig, batch):
     P = blocks.shape[0] * bs
     quant = cfg.kv_cache_quant
 
-    from repro.models.kv_cache import dequantize_kv, quantize_kv
+    from repro.models.kv_cache import dequantize_kv, quantize_kv, scale_slots
 
     tbl = jnp.maximum(blocks, 0)
-    pk = pool_k[:, tbl].reshape(L, P, *pool_k.shape[3:])
-    pv = pool_v[:, tbl].reshape(L, P, *pool_v.shape[3:])
+    pk = pool_k[:, tbl].reshape(L, P, cfg.n_kv_heads, cfg.head_dim)
+    pv = pool_v[:, tbl].reshape(L, P, cfg.n_kv_heads, cfg.head_dim)
     if quant:
-        ksc = batch["pool_k_scale"][:, tbl].reshape(L, P, cfg.n_kv_heads, 1)
-        vsc = batch["pool_v_scale"][:, tbl].reshape(L, P, cfg.n_kv_heads, 1)
+        ksc = scale_slots(batch["pool_k_scale"][:, tbl], bs).reshape(
+            L, P, cfg.n_kv_heads, 1)
+        vsc = scale_slots(batch["pool_v_scale"][:, tbl], bs).reshape(
+            L, P, cfg.n_kv_heads, 1)
         pk = dequantize_kv(pk, ksc)
         pv = dequantize_kv(pv, vsc)
 
@@ -596,8 +613,6 @@ def prefill_chunk(params, cfg: ModelConfig, cache: DecodeCache, batch,
     if cfg.attn_window:
         raise ValueError("chunked prefill requires a full-attention "
                          f"paged cache (attn_window={cfg.attn_window})")
-    from repro.kernels import ops
-
     tokens = batch["tokens"]
     B, Lc = tokens.shape
     lengths = jnp.asarray(batch["lengths"], jnp.int32)
@@ -605,48 +620,52 @@ def prefill_chunk(params, cfg: ModelConfig, cache: DecodeCache, batch,
     slot = jnp.asarray(batch["slot"], jnp.int32)
     blocks = jnp.asarray(batch["blocks"], jnp.int32)
     kv: PagedKVCache = cache.kv
-    quant = kv.quantized
-    L = cfg.num_layers
     length = lengths[0]
 
     spos = start + jnp.arange(Lc, dtype=jnp.int32)
     positions = jnp.broadcast_to(spos[None], (B, Lc))
     x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
-
-    def body(xc, layer_in):
-        block_p, pk, pv, ks, vs = layer_in
-        h = cm.apply_norm(xc, block_p["ln1"], cfg.norm)
-        q, k, v = _attention_qkv(block_p, cfg, h, positions)
-        with jax.named_scope("attention"):
-            attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
-                q, k, v, pk, pv, blocks, start, length,
-                k_scale=ks if quant else None,
-                v_scale=vs if quant else None,
-                softcap=cfg.attn_logit_softcap,
-            )
-        xn, _ = _block_post_attn_seq(block_p, cfg, xc, attn)
-        if quant:
-            ks, vs = ks_new, vs_new
-        return xn, (pk, pv, ks, vs)
-
-    ks_in = kv.k_scale if quant else jnp.zeros((L, 0))
-    vs_in = kv.v_scale if quant else jnp.zeros((L, 0))
-    x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-        body, x, (params["blocks"], kv.k, kv.v, ks_in, vs_in)
-    )
+    x, (k, v, ks, vs) = _chunk_layers(params, cfg, x, positions, _pool(kv),
+                                      blocks, start, length)
     logits = _head(params, cfg,
                    x if all_logits else cm.last_token_slice(x, lengths))
     total = start + length
     new_cache = DecodeCache(
         pos=cache.pos.at[slot].set(total),
-        kv=PagedKVCache(k=k_new, v=v_new, block_table=kv.block_table,
+        kv=PagedKVCache(k=k, v=v, block_table=kv.block_table,
                         length=kv.length.at[slot].set(total),
-                        k_scale=ks_new if quant else None,
-                        v_scale=vs_new if quant else None,
-                        block_size=kv.block_size),
+                        k_scale=ks, v_scale=vs, block_size=kv.block_size),
     )
     return new_cache, logits
+
+
+def _chunk_layers(params, cfg: ModelConfig, x, positions, pool, blocks,
+                  start, length):
+    """The layer scan of one chunk row: each layer attends and writes
+    through the fused ``ops.paged_prefill`` kernel on its layer of the
+    stacked pool planes, which the scan carries (never slices) and the
+    kernel updates in place. Shared by :func:`prefill_chunk` and the
+    verify rows of :func:`prefill_chunk_logits_multi`. Returns
+    ``(x, pool)``."""
+    from repro.kernels import ops
+
+    def body(carry, layer_in):
+        xc, (pk, pv, ks, vs) = carry
+        block_p, layer = layer_in
+        h = cm.apply_norm(xc, block_p["ln1"], cfg.norm)
+        q, k, v = _attention_qkv(block_p, cfg, h, positions)
+        with jax.named_scope("attention"):
+            attn, pk, pv, ks, vs = ops.paged_prefill(
+                q, k, v, pk, pv, blocks, start, length, layer=layer,
+                k_scale=ks, v_scale=vs, softcap=cfg.attn_logit_softcap,
+            )
+        xn, _ = _block_post_attn_seq(block_p, cfg, xc, attn)
+        return (xn, (pk, pv, ks, vs)), None
+
+    (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                (params["blocks"], _layer_ids(cfg)))
+    return x, pool
 
 
 def prefill_chunk_logits(params, cfg: ModelConfig, cache: DecodeCache, batch):
@@ -699,8 +718,6 @@ def prefill_chunk_logits_multi(params, cfg: ModelConfig, cache: DecodeCache,
     if cfg.attn_window:
         raise ValueError("chunked prefill requires a full-attention "
                          f"paged cache (attn_window={cfg.attn_window})")
-    from repro.kernels import ops
-
     tokens = batch["tokens"]
     R, Lc = tokens.shape
     lengths = jnp.asarray(batch["lengths"], jnp.int32)
@@ -708,56 +725,31 @@ def prefill_chunk_logits_multi(params, cfg: ModelConfig, cache: DecodeCache,
     slots = jnp.asarray(batch["slots"], jnp.int32)
     blocks = jnp.asarray(batch["blocks"], jnp.int32)
     kv: PagedKVCache = cache.kv
-    quant = kv.quantized
-    L = cfg.num_layers
 
     def row(carry, row_in):
-        pk_all, pv_all, ks_all, vs_all, pos, lng = carry
+        pool, pos, lng = carry
         toks_r, len_r, start_r, slot_r, blocks_r = row_in
         positions = (start_r + jnp.arange(Lc, dtype=jnp.int32))[None]
         x = _embed(params, cfg, toks_r[None])
         x = constrain(x, "batch", None, None)
-
-        def body(xc, layer_in):
-            block_p, pk, pv, ks, vs = layer_in
-            h = cm.apply_norm(xc, block_p["ln1"], cfg.norm)
-            q, k, v = _attention_qkv(block_p, cfg, h, positions)
-            with jax.named_scope("attention"):
-                attn, pk, pv, ks_new, vs_new = ops.paged_prefill(
-                    q, k, v, pk, pv, blocks_r, start_r, len_r,
-                    k_scale=ks if quant else None,
-                    v_scale=vs if quant else None,
-                    softcap=cfg.attn_logit_softcap,
-                )
-            xn, _ = _block_post_attn_seq(block_p, cfg, xc, attn)
-            if quant:
-                ks, vs = ks_new, vs_new
-            return xn, (pk, pv, ks, vs)
-
-        x, (pk_all, pv_all, ks_all, vs_all) = jax.lax.scan(
-            body, x, (params["blocks"], pk_all, pv_all, ks_all, vs_all)
-        )
+        x, pool = _chunk_layers(params, cfg, x, positions, pool, blocks_r,
+                                start_r, len_r)
         logits = _head(params, cfg, x)
         total = start_r + len_r
         sc = jnp.maximum(slot_r, 0)      # .at[-1] would wrap — clamp + gate
         live = slot_r >= 0
         pos = pos.at[sc].set(jnp.where(live, total, pos[sc]))
         lng = lng.at[sc].set(jnp.where(live, total, lng[sc]))
-        return (pk_all, pv_all, ks_all, vs_all, pos, lng), logits[0]
+        return (pool, pos, lng), logits[0]
 
-    ks_in = kv.k_scale if quant else jnp.zeros((L, 0))
-    vs_in = kv.v_scale if quant else jnp.zeros((L, 0))
-    carry = (kv.k, kv.v, ks_in, vs_in, cache.pos, kv.length)
-    (k_new, v_new, ks_new, vs_new, pos, lng), logits = jax.lax.scan(
+    carry = (_pool(kv), cache.pos, kv.length)
+    ((k, v, ks, vs), pos, lng), logits = jax.lax.scan(
         row, carry, (tokens, lengths, starts, slots, blocks)
     )
     new_cache = DecodeCache(
         pos=pos,
-        kv=PagedKVCache(k=k_new, v=v_new, block_table=kv.block_table,
-                        length=lng,
-                        k_scale=ks_new if quant else None,
-                        v_scale=vs_new if quant else None,
-                        block_size=kv.block_size),
+        kv=PagedKVCache(k=k, v=v, block_table=kv.block_table, length=lng,
+                        k_scale=ks, v_scale=vs, block_size=kv.block_size),
     )
     return new_cache, logits
 
@@ -811,41 +803,34 @@ def _decode_step_paged(params, cfg: ModelConfig, cache: DecodeCache, tokens,
                        fused: bool = True,
                        gather_blocks: Optional[int] = None):
     """decode_step over the shared block pool: one compiled signature for
-    any mix of slot depths and block-table layouts. `fused`/
-    `gather_blocks` select the fused kernel (default) vs the clamped
-    gather-then-attend reference path."""
+    any mix of slot depths and block-table layouts. The scan over layers
+    carries the stacked pool planes and each layer writes and attends
+    its own layer of them in place. `fused`/`gather_blocks` select the
+    fused kernel (default) vs the clamped gather-then-attend reference
+    path."""
     x = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
     pos = cache.pos
     kv: PagedKVCache = cache.kv
     table = kv.block_table
-    quant = kv.quantized
-    L = cfg.num_layers
 
-    def body(xc, layer_in):
-        block_p, pk, pv, ks, vs = layer_in
-        xn, pk, pv, ks, vs = block_decode_paged(
-            block_p, cfg, xc, pos, pk, pv, table, kv.block_size,
-            k_scale=ks if quant else None,
-            v_scale=vs if quant else None,
+    def body(carry, layer_in):
+        xc, pool = carry
+        block_p, layer = layer_in
+        xn, pool = block_decode_paged(
+            block_p, cfg, xc, pos, pool, table, kv.block_size, layer,
             fused=fused, gather_blocks=gather_blocks,
         )
-        if not quant:
-            ks, vs = layer_in[3], layer_in[4]  # dummy scan placeholders
-        return xn, (pk, pv, ks, vs)
+        return (xn, pool), None
 
-    ks_in = kv.k_scale if quant else jnp.zeros((L, 0))
-    vs_in = kv.v_scale if quant else jnp.zeros((L, 0))
-    x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-        body, x, (params["blocks"], kv.k, kv.v, ks_in, vs_in)
+    (x, (k, v, ks, vs)), _ = jax.lax.scan(
+        body, (x, _pool(kv)), (params["blocks"], _layer_ids(cfg))
     )
     logits = _head(params, cfg, x)
     new_cache = DecodeCache(
         pos=pos + 1,
-        kv=PagedKVCache(k=k_new, v=v_new, block_table=table,
-                        length=kv.length + 1,
-                        k_scale=ks_new if quant else None,
-                        v_scale=vs_new if quant else None,
+        kv=PagedKVCache(k=k, v=v, block_table=table,
+                        length=kv.length + 1, k_scale=ks, v_scale=vs,
                         block_size=kv.block_size),
     )
     return new_cache, logits

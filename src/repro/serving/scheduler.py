@@ -23,7 +23,7 @@ Design:
     served solo, in a static batch, or admitted while other slots are
     deep into their decodes, and whether the cache is contiguous or paged.
   * Paged KV cache (full-attention archs, default): a shared block pool
-    ``(L, num_blocks, block_size, NKV, H)`` plus per-slot block tables.
+    ``(L, num_blocks, block_size, NKV·H)`` plus per-slot block tables.
     Admission reserves the request's actual worst-case block count
     (``ceil((len + max_new - 1) / block_size)``) — when the pool can't
     cover it the request *queues* (no crash, no partial admission, no
@@ -106,7 +106,9 @@ from repro.models.kv_cache import (
     scatter_into_slot,
     scatter_suffix_into_paged,
     set_decode_positions,
+    scale_slots,
     set_paged_row,
+    split_heads,
     write_pool_block,
 )
 from repro.serving import sampling
@@ -983,6 +985,19 @@ class ContinuousScheduler:
                 * kv.k_scale.dtype.itemsize
         return per
 
+    def _host_planes(self, blk: int) -> tuple:
+        """Pool block `blk`'s (k, v, k_scale, v_scale) across every layer
+        as host numpy in the host tier's per-slot layout — codes
+        (L, block_size, NKV, H), scales (L, block_size, NKV, 1), None for
+        a bf16 pool — the bytes of the pool's rows, regrouped."""
+        kv = self.cache.kv
+        codes = tuple(split_heads(np.asarray(a[:, blk]), self.cfg.n_kv_heads)
+                      for a in (kv.k, kv.v))
+        scales = tuple(None if a is None else
+                       scale_slots(np.asarray(a[:, blk]), self.block_size)
+                       for a in (kv.k_scale, kv.v_scale))
+        return codes + scales
+
     def _spill_block(self, blk: int) -> None:
         """Move pool block `blk`'s bytes and digests to the host store.
         The caller owns the block's pool bookkeeping (it must already be
@@ -990,17 +1005,12 @@ class ContinuousScheduler:
         digest ownership: entries leave `_prefix_index`/`_block_hash` and
         land in `_host_index`, so no digest ever resolves to both a live
         device block and a stale host copy."""
-        kv = self.cache.kv
         digests = self._block_hash.pop(blk)
         for h in digests:
             self._prefix_index.pop(h, None)
+        k, v, ks, vs = self._host_planes(blk)
         entry = _HostBlock(
-            k=np.asarray(kv.k[:, blk]),
-            v=np.asarray(kv.v[:, blk]),
-            k_scale=(np.asarray(kv.k_scale[:, blk])
-                     if kv.quantized else None),
-            v_scale=(np.asarray(kv.v_scale[:, blk])
-                     if kv.quantized else None),
+            k=k, v=v, k_scale=ks, v_scale=vs,
             digests=set(digests),
             nbytes=self._host_block_nbytes(),
         )
@@ -1079,7 +1089,8 @@ class ContinuousScheduler:
 
     def _pool_geometry(self) -> dict:
         kv = self.cache.kv
-        shape = (kv.k.shape[0], kv.k.shape[2], kv.k.shape[3], kv.k.shape[4])
+        shape = (kv.k.shape[0], self.block_size, self.cfg.n_kv_heads,
+                 self.cfg.head_dim)
         return {"block_size": self.block_size,
                 "quantized": bool(kv.quantized),
                 "kv_shape": list(int(x) for x in shape),
@@ -1096,9 +1107,10 @@ class ContinuousScheduler:
         so the first same-prefix admission swaps the chunks back in
         instead of re-prefilling cold. Digest chains are tier-scoped at
         hash time, so mixed-tier indexes survive round trips unchanged."""
-        kv = self.cache.kv
 
-        def b64(a) -> str:
+        def b64(a) -> Optional[str]:
+            if a is None:
+                return None
             return base64.b64encode(np.ascontiguousarray(a).tobytes()) \
                 .decode("ascii")
 
@@ -1106,22 +1118,16 @@ class ContinuousScheduler:
         digests: Dict[str, int] = {}
         if self.paged:
             for blk, hs in self._block_hash.items():
-                entry = {"k": b64(np.asarray(kv.k[:, blk])),
-                         "v": b64(np.asarray(kv.v[:, blk])),
-                         "k_scale": (b64(np.asarray(kv.k_scale[:, blk]))
-                                     if kv.quantized else None),
-                         "v_scale": (b64(np.asarray(kv.v_scale[:, blk]))
-                                     if kv.quantized else None)}
+                entry = dict(zip(("k", "v", "k_scale", "v_scale"),
+                                 map(b64, self._host_planes(blk))))
                 idx = len(blocks)
                 blocks.append(entry)
                 for h in hs:
                     digests[h.hex()] = idx
             for hb in self._host_store.values():
                 entry = {"k": b64(hb.k), "v": b64(hb.v),
-                         "k_scale": (b64(hb.k_scale)
-                                     if hb.k_scale is not None else None),
-                         "v_scale": (b64(hb.v_scale)
-                                     if hb.v_scale is not None else None)}
+                         "k_scale": b64(hb.k_scale),
+                         "v_scale": b64(hb.v_scale)}
                 idx = len(blocks)
                 blocks.append(entry)
                 for h in hb.digests:
@@ -1343,10 +1349,13 @@ class ContinuousScheduler:
                 tab = tab.copy()
                 for b in self._chunk_plans:
                     tab[b, :] = -1
+            # A copy (`jnp.array`): the host keeps rewriting `_block_tab`
+            # while steps that read this device table are still queued,
+            # and on the CPU `jnp.asarray` may alias it.
             self.cache = dataclasses.replace(
                 self.cache,
                 kv=dataclasses.replace(
-                    self.cache.kv, block_table=jnp.asarray(tab)
+                    self.cache.kv, block_table=jnp.array(tab)
                 ),
             )
             self._table_dirty = False
@@ -1608,7 +1617,7 @@ class ContinuousScheduler:
                      * 2 * kv.k.dtype.itemsize)
         if kv.quantized:
             # int8 pool: add the per-(slot, head) fp32 k/v scale planes.
-            per_token += kv.k.shape[0] * kv.k.shape[3] * 2 * 4
+            per_token += kv.k.shape[0] * self.cfg.n_kv_heads * 2 * 4
         allocated = self._live_blocks
         hit_rate = (self.prefix_hit_tokens / self.prompt_tokens_seen
                     if self.prompt_tokens_seen else 0.0)
@@ -1769,7 +1778,7 @@ class ContinuousScheduler:
                 # scatter_into_paged also writes this row's table device-
                 # side; _table_dirty stays set so rows freed earlier sync.
                 self.cache = self._scatter_paged(
-                    self.cache, solo, slot, jnp.asarray(self._block_tab[slot])
+                    self.cache, solo, slot, jnp.array(self._block_tab[slot])
                 )
             else:
                 self.cache = self._scatter(self.cache, solo, slot)
@@ -1851,7 +1860,7 @@ class ContinuousScheduler:
             "start": jnp.asarray(start, jnp.int32),
             "pool_k": kv.k,
             "pool_v": kv.v,
-            "prefix_blocks": jnp.asarray(self._block_tab[slot, :nbp]),
+            "prefix_blocks": jnp.array(self._block_tab[slot, :nbp]),
         }
         if kv.quantized:
             batch["pool_k_scale"] = kv.k_scale
@@ -1862,12 +1871,12 @@ class ContinuousScheduler:
             # Below a full-prompt hit only whole blocks are shared, so the
             # suffix starts exactly at the block boundary `resident`.
             self.cache = self._scatter_suffix(
-                self.cache, solo, slot, jnp.asarray(self._block_tab[slot]),
+                self.cache, solo, slot, jnp.array(self._block_tab[slot]),
                 resident // self.block_size,
             )
         else:
             self.cache = self._set_row(
-                self.cache, solo, slot, jnp.asarray(self._block_tab[slot])
+                self.cache, solo, slot, jnp.array(self._block_tab[slot])
             )
         return logits
 
@@ -1946,7 +1955,7 @@ class ContinuousScheduler:
                 "lengths": jnp.asarray([t], jnp.int32),
                 "start": jnp.asarray(start, jnp.int32),
                 "slot": jnp.asarray(slot, jnp.int32),
-                "blocks": jnp.asarray(self._block_tab[slot, :nbp]),
+                "blocks": jnp.array(self._block_tab[slot, :nbp]),
             }
             self.cache, logits = self._chunk(
                 self._tier_views[self._slot_tier[slot]], self.cache, batch)
@@ -2178,7 +2187,10 @@ class ContinuousScheduler:
         Returns the (B, V) last-position logits matrix with each row taken
         from its own group's call, ready for the shared sampling path."""
         pos0 = np.asarray(self._pos_host, np.int32).copy()
-        cur = jnp.asarray(self._cur)
+        # Copies, here and for every `_block_tab` row handed to a jitted
+        # call: the host rewrites these arrays before an asynchronous call
+        # has read them, and on the CPU `jnp.asarray` may alias them.
+        cur = jnp.array(self._cur)
         out = None
         order = sorted(groups, key=lambda k: (k is not None, k or ""))
         for i, key in enumerate(order):

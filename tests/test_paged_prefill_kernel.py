@@ -30,24 +30,27 @@ from repro.kernels.registry import get_registry, pick_paged_prefill_blocks
 
 BS = 4          # pool block size
 NKV, G, H = 2, 3, 16
+LAYERS, LAYER = 3, 1   # stacked pool depth; the layer the cases write
 
 
-def _case(seed, *, quantized, start, length, lc, mb, alloc):
-    """Random pool + one row's block table with `alloc` live blocks.
-    The chunk covers prompt positions [start, start+length) inside an
-    Lc=`lc` padded call; `start` need not be block-aligned (warm prefix
-    ending mid-block)."""
+def _case(seed, *, quantized, start, length, lc, mb, alloc, layer=LAYER):
+    """Random stacked pool (LAYERS layers) + one row's block table with
+    `alloc` live blocks, written at `layer`. The chunk covers prompt
+    positions [start, start+length) inside an Lc=`lc` padded call;
+    `start` need not be block-aligned (warm prefix ending mid-block)."""
     assert start + length <= alloc * BS
     rng = np.random.default_rng(seed)
     nb = 8
+    shape = (LAYERS, nb, BS, NKV * H)
     if quantized:
-        pk = jnp.asarray(rng.integers(-128, 128, (nb, BS, NKV, H)), jnp.int8)
-        pv = jnp.asarray(rng.integers(-128, 128, (nb, BS, NKV, H)), jnp.int8)
-        ks = jnp.asarray(rng.random((nb, BS, NKV, 1)) * 0.02, jnp.float32)
-        vs = jnp.asarray(rng.random((nb, BS, NKV, 1)) * 0.02, jnp.float32)
+        pk = jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
+        pv = jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
+        rows = (LAYERS, nb, 1, NKV * BS)
+        ks = jnp.asarray(rng.random(rows) * 0.02, jnp.float32)
+        vs = jnp.asarray(rng.random(rows) * 0.02, jnp.float32)
     else:
-        pk = jnp.asarray(rng.standard_normal((nb, BS, NKV, H)), jnp.bfloat16)
-        pv = jnp.asarray(rng.standard_normal((nb, BS, NKV, H)), jnp.bfloat16)
+        pk = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        pv = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
         ks = vs = None
     q = jnp.asarray(rng.standard_normal((1, lc, NKV * G, H)), jnp.bfloat16)
     kn = jnp.asarray(rng.standard_normal((1, lc, NKV, H)), jnp.bfloat16)
@@ -55,17 +58,20 @@ def _case(seed, *, quantized, start, length, lc, mb, alloc):
     blocks = np.full(mb, -1, np.int32)
     blocks[:alloc] = rng.permutation(np.arange(1, nb))[:alloc]
     return (q, kn, vn, pk, pv, jnp.asarray(blocks),
-            jnp.int32(start), jnp.int32(length), ks, vs)
+            jnp.int32(start), jnp.int32(length), ks, vs, jnp.int32(layer))
+
+
+def _kernel(case, *, bh, softcap=0.0, backend="interpret"):
+    return ops.paged_prefill(*case[:8], layer=case[10], k_scale=case[8],
+                             v_scale=case[9], softcap=softcap,
+                             blocks_plan=(bh, BS, H), backend=backend)
 
 
 def _both(case, *, bh, softcap=0.0):
     ref = jax.jit(functools.partial(kref.paged_prefill_ref,
                                     softcap=softcap))(
-        *case[:8], k_scale=case[8], v_scale=case[9])
-    out = ops.paged_prefill(*case[:8], k_scale=case[8], v_scale=case[9],
-                            softcap=softcap, blocks_plan=(bh, BS, H),
-                            backend="interpret")
-    return ref, out
+        *case[:8], case[10], k_scale=case[8], v_scale=case[9])
+    return ref, _kernel(case, bh=bh, softcap=softcap)
 
 
 def _assert_bitwise(ref, out):
@@ -76,7 +82,7 @@ def _assert_bitwise(ref, out):
             continue
         r, o = np.asarray(r), np.asarray(o)
         if name != "attn":
-            r, o = r[1:], o[1:]  # trash block: contents undefined
+            r, o = r[:, 1:], o[:, 1:]  # trash block: contents undefined
         assert np.array_equal(r, o), name
 
 
@@ -110,8 +116,8 @@ def test_padded_short_and_empty_chunks(start, length, lc):
     ref, out = _both(case, bh=2)
     _assert_bitwise(ref, out)
     if length == 0:
-        assert np.array_equal(np.asarray(out[1])[1:],
-                              np.asarray(case[3])[1:])
+        assert np.array_equal(np.asarray(out[1])[:, 1:],
+                              np.asarray(case[3])[:, 1:])
 
 
 def test_softcap_int8():
@@ -128,33 +134,64 @@ def test_resident_prefix_blocks_untouched():
     _, out = _both(case, bh=2)
     tbl = np.asarray(case[5])
     for blk in tbl[:2]:  # blocks 0,1 cover positions [0, 8) — all prefix
-        assert np.array_equal(np.asarray(out[1])[blk],
-                              np.asarray(case[3])[blk])
-        assert np.array_equal(np.asarray(out[2])[blk],
-                              np.asarray(case[4])[blk])
+        assert np.array_equal(np.asarray(out[1])[:, blk],
+                              np.asarray(case[3])[:, blk])
+        assert np.array_equal(np.asarray(out[2])[:, blk],
+                              np.asarray(case[4])[:, blk])
 
 
 def test_trash_block_garbage_never_leaks():
     """Huge garbage in pool block 0 (where dead writes land) must not
     change the chunk's attention output."""
     case = _case(5, quantized=False, start=4, length=6, lc=8, mb=4, alloc=3)
-    clean = ops.paged_prefill(*case[:8], backend="interpret",
-                              blocks_plan=(2, BS, H))
-    pk = case[3].at[0].set(jnp.full(case[3].shape[1:], 1e4, case[3].dtype))
-    pv = case[4].at[0].set(jnp.full(case[4].shape[1:], 1e4, case[4].dtype))
-    dirty = ops.paged_prefill(*case[:3], pk, pv, *case[5:8],
-                              backend="interpret", blocks_plan=(2, BS, H))
+    clean = _kernel(case, bh=2)
+    pk = case[3].at[:, 0].set(1e4)
+    pv = case[4].at[:, 0].set(1e4)
+    dirty = _kernel(case[:3] + (pk, pv) + case[5:], bh=2)
     assert np.array_equal(np.asarray(clean[0]), np.asarray(dirty[0]))
 
 
 def test_reference_backend_dispatch():
     """backend="reference" routes to paged_prefill_ref itself."""
     case = _case(6, quantized=False, start=0, length=8, lc=8, mb=4, alloc=2)
-    out = ops.paged_prefill(*case[:8], backend="reference")
-    ref = jax.jit(kref.paged_prefill_ref)(*case[:8])
+    out = ops.paged_prefill(*case[:8], layer=case[10], backend="reference")
+    ref = jax.jit(kref.paged_prefill_ref)(*case[:8], case[10])
     for r, o in zip(ref[:3], out[:3]):
         np.testing.assert_allclose(np.asarray(r, np.float32),
                                    np.asarray(o, np.float32))
+
+
+@pytest.mark.parametrize("layer", [0, LAYER, LAYERS - 1],
+                         ids=["first", "middle", "last"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_stacked_pool_layer_matches_per_layer_call(quantized, layer):
+    """The kernel on the whole stacked pool at `layer` is bitwise the
+    per-layer call — the same kernel on a one-layer pool holding only
+    that layer's planes: the same attention and the same written layer.
+    It changes only that layer's destination blocks: every other layer,
+    and every other block of that layer, keeps its exact input bytes."""
+    case = _case(8, quantized=quantized, start=6, length=7, lc=8, mb=6,
+                 alloc=4, layer=layer)
+    one = lambda a: None if a is None else a[layer:layer + 1]  # noqa: E731
+    per_layer = case[:3] + (one(case[3]), one(case[4])) + case[5:8] + (
+        one(case[8]), one(case[9]), jnp.int32(0))
+    out = _kernel(case, bh=2)
+    alone = _kernel(per_layer, bh=2)
+    assert np.array_equal(np.asarray(out[0]), np.asarray(alone[0]))
+    tbl = np.asarray(case[5])
+    written = set(tbl[6 // BS:-(-13 // BS)].tolist()) | {0}   # + trash
+    for plane, before, single in zip(out[1:], case[3:5] + case[8:10],
+                                     alone[1:]):
+        if before is None:
+            assert plane is None and single is None
+            continue
+        plane, before = np.asarray(plane), np.asarray(before)
+        assert np.array_equal(plane[layer, 1:], np.asarray(single)[0, 1:])
+        for other in set(range(LAYERS)) - {layer}:
+            assert np.array_equal(plane[other], before[other])
+        kept = sorted(set(range(plane.shape[1])) - written)
+        assert np.array_equal(plane[layer, kept], before[layer, kept])
+        assert not np.array_equal(plane[layer], before[layer])
 
 
 # -- registry plan plumbing --------------------------------------------------
