@@ -55,6 +55,7 @@ class RunData:
     counters: Dict[str, float]       # program counters, window deltas
     trace: Optional[tr.Trace]
     base: Path
+    model: object                    # the configuration's model module
 
     def traced_steps(self) -> List[StepRecord]:
         return [s for s in self.steps if s.traced]
@@ -233,7 +234,7 @@ def drive_closed(sched, rec, ann, tracer, clients, seconds, on_open):
 
     def send(c: int) -> None:
         if not queues[c]:
-            raise RuntimeError(f"client {c} ran out of planned requests")
+            return              # the client has sent all it planned: it stops
         p = queues[c].popleft()
         req = request(p.rid, p.prompt, p.max_new, p.temperature)
         owner[p.rid] = c
@@ -258,6 +259,9 @@ def drive_closed(sched, rec, ann, tracer, clients, seconds, on_open):
         elif now >= end:
             break
         tracer.tick(now)
+        if not (sched.num_waiting or sched.num_active):
+            time.sleep(0.001)       # every client has stopped
+            continue
         for r in rec.step(sched, ann):
             with ann("bench.submit"):
                 send(owner[r.rid])
@@ -266,9 +270,10 @@ def drive_closed(sched, rec, ann, tracer, clients, seconds, on_open):
 
 
 def _pool_counters(sched) -> Dict[str, float]:
-    st = sched.pool_stats()
-    return {k: float(st.get(k, 0)) for k in
-            ("prefix_hit_tokens", "prompt_tokens", "kernel_fallbacks")}
+    """Every number `pool_stats()` gives (flags and nested groups left
+    out)."""
+    return {k: float(v) for k, v in sched.pool_stats().items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
 
 
 @dataclasses.dataclass
@@ -279,6 +284,7 @@ class Cell:
     peaks: dict
     dev: object
     device_count: int
+    model: object
     engine: object
     sched: object
     rec: Recorder
@@ -321,11 +327,12 @@ def set_up(bench: dict, cell: str, seed: int, trace: bool, *,
             raise NoChip(f"kernel backend is {backend!r}, not 'mosaic'")
     t_init = time.perf_counter()
 
-    params = serve.make_params(m, seed)
+    model = spec.model(m["model"], base)
+    params = model.make_params(m, seed)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
     rec = Recorder()
-    engine = serve.build_engine(m, seed, params, rec.on_token)
+    engine = serve.build_engine(model, m, seed, params, rec.on_token)
     del params
     sched = engine.scheduler()
     t_engine = time.perf_counter()
@@ -340,8 +347,8 @@ def set_up(bench: dict, cell: str, seed: int, trace: bool, *,
         f"{t_warm - t_engine:.3f} s ({compiles.count} compiles, "
         f"{compiles.seconds:.3f} s, {compiles.hits} persistent-cache hits, "
         f"cache {cache_dir})")
-    return Cell(m, mix, peaks, dev, len(devices), engine, sched, rec, ann,
-                compiles, base, t_warm)
+    return Cell(m, mix, peaks, dev, len(devices), model, engine, sched, rec,
+                ann, compiles, base, t_warm)
 
 
 def run_window(c: Cell, seed: int, seconds: float, trace_dir=None,
@@ -412,10 +419,17 @@ def run_cell(bench: dict, cell: str, seed: int, seconds: float, trace: bool,
               "failed": e2e.pop("failed")}
     metrics, breakdown = {}, None
     if trace:
-        trc = tr.read(trace_dir)
+        trc = tr.read(trace_dir,
+                      tr.SCOPES | set(getattr(c.model, "SCOPES", ())))
         _remove(trace_dir)
         if trc is not None:
             log("trace planes: " + " | ".join(trc.planes))
+            by_step = tr.step_scopes(trc)
+            if by_step is not None:
+                log(f"device ms a step over {by_step['steps']} serving.step "
+                    f"spans, by scope: {by_step['ms']}; sum "
+                    f"{sum(by_step['ms'].values())}, busy inside the steps "
+                    f"{by_step['busy_ms']}")
             lo, hi = tr.window(trc)
             device["busy_s"] = tr.busy_ns(trc) * 1e-9
             device["window_s"] = (hi - lo) * 1e-9
@@ -423,7 +437,8 @@ def run_cell(bench: dict, cell: str, seed: int, seconds: float, trace: bool,
                          "idle_gaps": tr.idle_gaps(trc)}
         c0, c1 = win["counters0"], win["counters1"]
         run = RunData(m, c.peaks, win["steps"],
-                      {k: c1[k] - c0[k] for k in c1}, trc, c.base)
+                      {k: c1[k] - c0.get(k, 0.0) for k in c1}, trc, c.base,
+                      c.model)
         for entry in spec.cell_metrics(bench, cell, True):
             v = spec.metric_reader(entry["name"], c.base)(run, entry["name"])
             if v is not None:

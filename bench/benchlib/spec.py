@@ -5,6 +5,7 @@ lives in a file of its own under the benchmark's directory, found here by
 that name alone:
 
     configs/<config>.json       (the path the configuration entry gives)
+    models/<model>.py           (named by the configuration file's "model")
     traffic/<mix>.json
     metrics/<metric>.py         or metrics/<base>.py, base = the metric's
                                 name up to its first "." (one reader for
@@ -43,7 +44,12 @@ def config(bench: dict, name: str, root: Path = ROOT) -> dict:
     for c in bench["configs"]:
         if c["name"] == name:
             with open(root / c["file"]) as f:
-                return json.load(f)
+                m = json.load(f)
+            if "model" not in m:
+                raise KeyError(f"configuration file {c['file']} has no "
+                               "\"model\" key: it names the module under "
+                               "models/ that builds the model")
+            return m
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
 
@@ -69,6 +75,14 @@ def metric_reader(name: str, base: Path = BENCH):
 
 def roofline(kernel: str, base: Path = BENCH):
     return _module(base / "roofline" / f"{kernel}.py", "roofline_" + kernel)
+
+
+def model(name: str, base: Path = BENCH):
+    """The model module `name`: `model_config(m)`, `make_params(m, seed)`,
+    `pool_blocks(m, block_size)` and `flops(m, tokens, keys, logits_rows)`;
+    an optional `SCOPES` adds the device scopes it names to those the
+    trace reduction attributes operations to."""
+    return _module(base / "models" / f"{name}.py", "model_" + name)
 
 
 def reference(name: str, base: Path = BENCH):

@@ -1,11 +1,10 @@
 """mfu.*: the whole step's share of the chip's bf16 peak, in percent.
 Operations the model requires for the tokens the window prefilled and
-decoded (`roofline/model.py`, from the configuration), over the summed
+decoded (the `flops` of the configuration's model module), over the summed
 host-clock spans of the window's steps times the bf16 peak."""
 
 
 def read(run, name):
-    model = run.roofline("model")
     busy = sum(s.t1 - s.t0 for s in run.steps)
     if busy <= 0:
         return None
@@ -18,5 +17,5 @@ def read(run, name):
         rows += s.logits_rows
     if tokens == 0:
         return None
-    ops = model.flops(run.m, tokens, keys, rows)
+    ops = run.model.flops(run.m, tokens, keys, rows)
     return 100.0 * ops / (busy * run.peaks["bf16_flops_per_s"])

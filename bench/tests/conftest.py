@@ -21,9 +21,9 @@ for p in (str(ROOT / "src"), str(BENCH)):
 # benchmark runs on the chip yet.
 OLMO_1B = dict(
     name="olmo-1b", source="https://huggingface.co/allenai/OLMo-1B",
-    reference="dense_transformer", num_layers=16, d_model=2048, n_heads=16,
-    n_kv_heads=16, head_dim=128, d_ff=8192, vocab=50304,
-    context_length=2048, rope_theta=10000.0, ffn="swiglu",
+    model="dense_transformer", reference="dense_transformer", num_layers=16,
+    d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128, d_ff=8192,
+    vocab=50304, context_length=2048, rope_theta=10000.0, ffn="swiglu",
     norm="nonparam_ln", qk_norm=False, tie_embeddings=True,
     serving=dict(weights="w4a8", kv_int8=True, max_batch=32,
                  pool_bytes=3 << 30),

@@ -45,6 +45,20 @@ def test_traced_run_reports_layer_metrics(tiny):
     assert "setup_s" not in r["metrics"]
 
 
+def test_closed_loop_client_stops_when_its_plan_runs_out(tiny):
+    """A client that has sent every request it planned stops, and the
+    window runs to its end: with one request a client, the batch fills,
+    each client's one request finishes, and the run is still judged."""
+    root, bench = tiny
+    path = root / "bench" / "traffic" / "batch-decode.json"
+    mix = json.loads(path.read_text())
+    mix["per_client"] = 1
+    path.write_text(json.dumps(mix))
+    r = run(tiny, "stablelm12b-pp4.batch-decode")
+    assert r["correct"], r["checks"]
+    assert r["attempted"] <= mix["clients"]
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_altered_token_is_not_correct(tiny, monkeypatch, cell):
     """A greedy token altered where it is produced (the sampler) makes
@@ -104,7 +118,7 @@ def test_control_reads_far_above_served(tiny, cell):
 def test_params_match_the_programs_packed_tree(tiny):
     import jax
 
-    from benchlib import serve
+    from benchlib import spec
     from repro.core.precision import parse_quant_token
     from repro.core.quantized_linear import quantize_params_for_serving
     from repro.models import build_model
@@ -112,8 +126,9 @@ def test_params_match_the_programs_packed_tree(tiny):
     root, bench = tiny
     for c in bench["configs"]:
         m = json.loads((root / c["file"]).read_text())
-        ours = serve.make_params(m, SEED)
-        cfg = serve.model_config(m)
+        model = spec.model(m["model"], root / "bench")
+        ours = model.make_params(m, SEED)
+        cfg = model.model_config(m)
         theirs = quantize_params_for_serving(
             build_model(cfg).init(jax.random.PRNGKey(0)),
             parse_quant_token(m["serving"]["weights"]), min_size=1024)

@@ -30,8 +30,9 @@ def program_spans(trace_dir) -> list:
 def test_spans_match_the_harness_record(tiny, tmp_path):
     root, bench = tiny
     m = spec.config(bench, "stablelm-12b-pp4", root)
+    model = spec.model(m["model"], root / "bench")
     rec = harness.Recorder()
-    engine = serve.build_engine(m, SEED, serve.make_params(m, SEED),
+    engine = serve.build_engine(model, m, SEED, model.make_params(m, SEED),
                                 rec.on_token)
     sched = engine.scheduler()
     harness._observe_chunks(sched, rec)

@@ -42,7 +42,7 @@ def test_gqa_counts_kv_heads_once():
 
 
 def test_model_flops_hand_count():
-    model = spec.roofline("model")
+    model = spec.model("dense_transformer")
     m = olmo()
     p = 16 * (4 * 2048 * 2048 + 3 * 2048 * 8192)
     assert model.layer_params(m) == p

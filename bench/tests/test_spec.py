@@ -17,16 +17,20 @@ def test_peaks_known_and_unknown_device():
 
 
 def test_new_cell_found_from_files_alone(tmp_path):
-    """A new configuration, mix, metric and kernel roofline are found by
-    the names BENCHMARK.json gives them, with no code edited."""
+    """A new configuration, model module, mix, metric and kernel roofline
+    are found by the names BENCHMARK.json gives them, with no code
+    edited."""
     b = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics", "roofline"):
+    for d in ("configs", "traffic", "metrics", "roofline", "models"):
         (b / d).mkdir(parents=True)
-    (b / "configs" / "toy.json").write_text(json.dumps({"name": "toy"}))
+    (b / "configs" / "toy.json").write_text(
+        json.dumps({"name": "toy", "model": "toy_model"}))
     (b / "traffic" / "burst.json").write_text(json.dumps({"loop": "open"}))
     (b / "metrics" / "queue_ms.py").write_text(
         "def read(run, name):\n    return 7.0 if name.endswith('.a') "
         "else 8.0\n")
+    (b / "models" / "toy_model.py").write_text(
+        "def flops(m, tokens, keys, logits_rows):\n    return 3.0 * tokens\n")
     (b / "roofline" / "my_kernel.py").write_text(
         "NAMES = ('k',)\ndef cost(m, call):\n    return 1.0, 2.0\n")
     bench = {
@@ -39,8 +43,10 @@ def test_new_cell_found_from_files_alone(tmp_path):
                       {"name": "queue_ms.b", "moves": "ttft"}],
     }
     w = spec.workload(bench, "toy.burst")
-    assert spec.config(bench, w["config"], tmp_path) == {"name": "toy"}
+    assert spec.config(bench, w["config"], tmp_path) == {
+        "name": "toy", "model": "toy_model"}
     assert spec.traffic(w["traffic"], b) == {"loop": "open"}
+    assert spec.model("toy_model", b).flops({}, 5, 0, 0) == 15.0
     assert spec.metric_reader("queue_ms.a", b)(None, "queue_ms.a") == 7.0
     assert spec.roofline("my_kernel", b).cost({}, {}) == (1.0, 2.0)
     names = [e["name"] for e in spec.cell_metrics(bench, "toy.burst", True)]
@@ -55,7 +61,16 @@ def test_every_named_piece_of_the_benchmark_exists():
         m = spec.config(bench, w["config"])
         spec.traffic(w["traffic"])
         spec.reference(m["reference"])
+        spec.model(m["model"])
         for e in spec.cell_metrics(bench, w["name"], True):
             assert callable(spec.metric_reader(e["name"]))
-    for kernel in ("paged_attention", "paged_prefill", "model"):
+    for kernel in ("paged_attention", "paged_prefill"):
         spec.roofline(kernel)
+
+
+def test_config_without_model_fails_at_load(tmp_path):
+    (tmp_path / "old.json").write_text(
+        json.dumps({"name": "old", "reference": "dense_transformer"}))
+    bench = {"configs": [{"name": "old", "file": "old.json"}]}
+    with pytest.raises(KeyError, match='"model"'):
+        spec.config(bench, "old", tmp_path)
